@@ -23,6 +23,9 @@ def main() -> None:
                     help="write structured results (default BENCH_kernels.json)")
     args, _ = ap.parse_known_args()
 
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
+
     from . import (table2_3_marginals_scaling, table4_5_accuracy,
                    table6_9_rplus, table10_14_crossover, fig1_3_fairness,
                    discrete_overhead, discrete_bench, kernels_bench,

@@ -20,14 +20,18 @@ from typing import List, Optional, Sequence, Union
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 Factor = Union[None, str, np.ndarray, "jnp.ndarray"]
 
 
 def _apply_axis_jnp(x, mat, axis: int):
+    # HIGHEST: on a TPU the default float32 contraction is a reduced-precision
+    # MXU pass, too coarse for counts in the hundreds of thousands.
     x = jnp.moveaxis(x, axis, 0)
-    y = jnp.tensordot(mat, x, axes=([1], [0]))
+    y = jnp.tensordot(mat, x, axes=([1], [0]),
+                      precision=jax.lax.Precision.HIGHEST)
     return jnp.moveaxis(y, 0, axis)
 
 

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from .domain import Clique, Domain
-from .kron import kron_matvec, kron_matvec_batched, kron_matvec_np
+from .kron import kron_matvec, kron_matvec_np
 from .residual import p_coeff, sub_matrix
 from .select import Plan
 
@@ -94,7 +94,7 @@ _noise_dtype = noise_dtype   # backward-compat alias
 
 
 def measure(plan: Plan, marginals: Mapping[Clique, jnp.ndarray],
-            key: jax.Array, use_kernel: bool = False,
+            key: jax.Array, use_kernel: Optional[bool] = None,
             batched: bool = True, dtype=None) -> Dict[Clique, Measurement]:
     """Run every base mechanism in the plan (Algorithm 1, continuous Gaussian).
 
@@ -111,44 +111,17 @@ def measure(plan: Plan, marginals: Mapping[Clique, jnp.ndarray],
     keeps the historical per-clique loop (oracle / benchmark baseline).
 
     ``dtype`` governs the noise draws; ``None`` resolves to
-    :func:`noise_dtype` (float64 under jax x64).
+    :func:`noise_dtype` (float64 under jax x64).  ``use_kernel=None``
+    resolves from the backend (the Pallas chains on a TPU).
     """
+    if batched:
+        from repro.engine.multi import measure_batch
+        return measure_batch([(plan, marginals, key)], use_kernel, dtype)[0]
+    from repro.kernels.kron_matvec._layout import resolve_use_kernel
     dtype = _noise_dtype() if dtype is None else dtype
-    keys = jax.random.split(key, len(plan.cliques))
-    if not batched:
-        return _measure_loop(plan, marginals, dict(zip(plan.cliques, keys)),
-                             use_kernel, dtype)
-
-    out: Dict[Clique, Measurement] = {}
-    pos = {c: i for i, c in enumerate(plan.cliques)}
-    for dims, cliques in signature_groups(plan.domain, plan.cliques).items():
-        m = int(np.prod(dims)) if dims else 1
-        g = len(cliques)
-        vs = []
-        for c in cliques:
-            v = jnp.asarray(marginals[c]).reshape(-1)
-            if v.shape[0] != m:
-                raise ValueError(f"marginal for {c} has {v.shape[0]} cells, want {m}")
-            vs.append(v)
-        # One vectorized draw per group (bit-identical to the per-clique
-        # loop: vmapped threefry matches per-key normal draws exactly).
-        z = jax.vmap(lambda k: jax.random.normal(k, (m,), dtype=dtype))(
-            keys[jnp.asarray([pos[c] for c in cliques])])
-        sig = jnp.asarray([math.sqrt(plan.sigmas[c]) for c in cliques])[:, None]
-        if not dims:
-            om = jnp.stack(vs) + sig * z
-        else:
-            x = jnp.concatenate([jnp.stack(vs), z], axis=0)   # (2g, m)
-            factors = [sub_matrix(n) for n in dims]
-            if use_kernel:
-                from repro.kernels.kron_matvec.fused import fused_chain_matvec
-                y = fused_chain_matvec(factors, x, dims)
-            else:
-                y = kron_matvec_batched(factors, x, dims)
-            om = y[:g] + sig * y[g:]
-        for i, c in enumerate(cliques):
-            out[c] = Measurement(c, np.asarray(om[i]), plan.sigmas[c])
-    return out
+    keys = np.asarray(jax.random.split(key, len(plan.cliques)))
+    return _measure_loop(plan, marginals, dict(zip(plan.cliques, keys)),
+                         resolve_use_kernel(use_kernel), dtype)
 
 
 def _measure_loop(plan: Plan, marginals: Mapping[Clique, jnp.ndarray],

@@ -12,10 +12,13 @@ mutually consistent.  The per-axis factors of U_{A←A'} are:
 from __future__ import annotations
 
 import math
+import time
+from functools import lru_cache
 from typing import Dict, List, Mapping, Optional, Sequence
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from .domain import Clique, Domain, subsets
@@ -147,19 +150,19 @@ def reconstruct_all_batched(plan: Plan, measurements: Mapping[Clique, Measuremen
     U-chain ⊗ T_i exactly), their embedded subset-answer tensors are stacked
     into the batch axis, and each group runs as a single fused chain
     (docs/DESIGN.md §5) — 2^|A| × #cliques matvecs collapse to one pallas_call
-    per signature.
+    per signature, and every group of the call runs in one compiled program.
 
     ``use_kernel=None`` resolves per backend: the fused Pallas chain on TPU,
     the batched jnp path elsewhere (interpret-mode Pallas is a correctness
     vehicle, not a CPU fast path — see benchmarks/kernels_bench.py).
     """
     from .mechanism import signature_groups
-    from .kron import kron_matvec_batched
-    if use_kernel is None:
-        from repro.kernels.kron_matvec._layout import interpret_default
-        use_kernel = not interpret_default()
+    from repro.kernels.kron_matvec._layout import resolve_use_kernel
+    from repro.kernels.kron_matvec.fused import prepare_chain
+    use_kernel = resolve_use_kernel(use_kernel)
     cliques = list(plan.workload.cliques if cliques is None else cliques)
     out: Dict[Clique, np.ndarray] = {}
+    specs, args, groups = [], [], []
     for sizes, group in signature_groups(plan.domain, cliques).items():
         if not sizes:
             for c in group:
@@ -168,15 +171,45 @@ def reconstruct_all_batched(plan: Plan, measurements: Mapping[Clique, Measuremen
         x = np.stack([embed_subset_answers(plan, measurements, c).reshape(-1)
                       for c in group])
         factors = u_chain_factors(plan.domain, group[0])
+        launch = None
         if use_kernel:
-            from repro.kernels.kron_matvec.fused import fused_chain_matvec
-            y = np.asarray(fused_chain_matvec(factors, x, sizes,
-                                              allow_narrow=True))
+            # Reconstruction carries no noise lanes: a tuned narrow compute
+            # dtype (fp32 accumulation) may serve it (docs/DESIGN.md §14).
+            launch = prepare_chain(factors, sizes, len(group),
+                                   allow_narrow=True)
+            operands = launch.operands
+            x = x.astype(np.float32)
+            if not launch.fused:          # the per-axis chain takes it N-D
+                x = x.reshape((len(group),) + tuple(sizes))
         else:
-            y = np.asarray(kron_matvec_batched(factors, x, sizes))
+            operands = tuple(jnp.asarray(f) for f in factors)
+        specs.append((tuple(sizes), launch))
+        args.append((x, operands))
+        groups.append(group)
+    if not specs:
+        return out
+    t0 = time.monotonic()
+    ys = _reconstruct_program(tuple(specs))(tuple(args))
+    for (sizes, launch), group, y in zip(specs, groups, ys):
+        if launch is not None:
+            launch.record(len(group), t0)
+        y = np.asarray(y).reshape(len(group), -1)
         for i, c in enumerate(group):
             out[c] = y[i]
     return out
+
+
+@lru_cache(maxsize=64)
+def _reconstruct_program(specs):
+    """One jitted program for every signature group of a reconstruction."""
+    from .kron import kron_matvec_batched
+
+    def run(args):
+        return tuple(kron_matvec_batched(operands, x, sizes) if launch is None
+                     else launch.apply(operands, x)
+                     for (sizes, launch), (x, operands) in zip(specs, args))
+
+    return jax.jit(run)
 
 
 def marginal_variance(plan: Plan, clique: Clique) -> float:

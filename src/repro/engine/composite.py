@@ -39,10 +39,9 @@ class CompositeEngine(ReleaseServing):
 
     def __init__(self, plan, use_kernel: Optional[bool] = None,
                  precompile: bool = True, dtype=None):
-        from repro.kernels.kron_matvec._layout import interpret_default
+        from repro.kernels.kron_matvec._layout import resolve_use_kernel
         self.plan = plan
-        self.use_kernel = (not interpret_default()) if use_kernel is None \
-            else use_kernel
+        self.use_kernel = resolve_use_kernel(use_kernel)
         self.dtype = noise_dtype() if dtype is None else dtype
         self.stats = EngineStats()
         self._engines = [self._child_engine(bp, precompile)

@@ -53,7 +53,7 @@ from repro.core.plantable import BasePlan
 from repro.core.reconstruct import reconstruct_all_batched, u_chain_factors
 from repro.engine.engine import ChainRegistry, EngineStats, ReleaseServing
 from repro.obs import TRACER
-from repro.kernels.kron_matvec._layout import interpret_default
+from repro.kernels.kron_matvec._layout import resolve_use_kernel
 from repro.kernels.kron_matvec.fused import fused_chain_matvec
 
 # f32 chains hold integers exactly below 2^24, f64 below 2^53.
@@ -107,8 +107,7 @@ class DiscreteEngine(ReleaseServing, ChainRegistry):
                              " plan; RP+ plans have no integer-query rotation")
         self.plan = plan
         self.digits = digits
-        self.use_kernel = (not interpret_default()) if use_kernel is None \
-            else use_kernel
+        self.use_kernel = resolve_use_kernel(use_kernel)
         self.dtype = noise_dtype() if dtype is None else dtype
         self.stats = EngineStats()
         # Exact per-clique σ̄/γ² (Alg 3 lines 1-2), computed once.

@@ -34,7 +34,8 @@ from repro.core.reconstruct import reconstruct_all_batched, u_chain_factors
 from repro.core.residual import sub_matrix
 from repro.core.select import Plan
 from repro.kernels.kron_matvec._layout import pad_to
-from repro.kernels.kron_matvec.fused import fused_chain_matvec, plan_chain
+from repro.kernels.kron_matvec.fused import (chain_fuses, fused_chain_matvec,
+                                             plan_chain)
 from repro.obs import REGISTRY, TRACER, AtomicCounter
 
 # Process-wide aggregate of every EngineStats bump, labeled by counter name —
@@ -151,10 +152,9 @@ class ChainRegistry:
             cp = plan_chain(factors, dims, batch=batch, block_l=cfg.block_l,
                             vmem_budget=cfg.vmem_budget, epilogue=epilogue,
                             compute_dtype=dt)
-            fused = cfg.fused and cp.fused_ok
         else:
             cp = plan_chain(factors, dims, batch=batch, epilogue=epilogue)
-            fused = cp.fused_ok
+        fused = chain_fuses(factors, dims, epilogue, cp.compute_dtype)
         key = (tuple(dims), cp.signature, pad_to(batch, cp.block_l))
         if key not in self._chain_plans:
             self._chain_plans[key] = (cp, factors, batch, epilogue)
@@ -207,15 +207,15 @@ class ChainRegistry:
         """Layout report: one row per compiled chain (for ops/debugging)."""
         rows = []
         tune = getattr(self, "_chain_tune", {})
-        for key, (cp, _f, batch, _e) in self._chain_plans.items():
-            (dims, sig, b_p) = key
+        for key, (cp, factors, batch, epilogue) in self._chain_plans.items():
+            dims, _sig, b_p = key
             cfg = tune.get(key)
             rows.append(dict(dims=dims, batch=batch, batch_padded=b_p,
                              w_in=cp.w_in, w_out=cp.w_out, block_l=cp.block_l,
                              vmem_bytes=cp.vmem_bytes,
-                             fused=(cfg.fused and cp.fused_ok) if cfg
-                             else cp.fused_ok,
-                             epilogue=sig[3],
+                             fused=chain_fuses(factors, dims, epilogue,
+                                               cp.compute_dtype),
+                             epilogue=cp.epilogue,
                              compute_dtype=cp.compute_dtype,
                              tuned=cfg is not None,
                              tune_source=cfg.source if cfg else "default",
@@ -312,10 +312,9 @@ class MarginalEngine(ReleaseServing, ChainRegistry):
     def __init__(self, plan: Plan, use_kernel: Optional[bool] = None,
                  precompile: bool = True, dtype=None):
         from repro.core.mechanism import noise_dtype
-        from repro.kernels.kron_matvec._layout import interpret_default
+        from repro.kernels.kron_matvec._layout import resolve_use_kernel
         self.plan = plan
-        self.use_kernel = (not interpret_default()) if use_kernel is None \
-            else use_kernel
+        self.use_kernel = resolve_use_kernel(use_kernel)
         self.dtype = noise_dtype() if dtype is None else dtype
         self.stats = EngineStats()
         self._measure_groups = signature_groups(plan.domain, plan.cliques)

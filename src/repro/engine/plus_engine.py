@@ -57,7 +57,7 @@ from repro.core.plus import (PlusPlan, measure_chain_split,
                              plus_signature_groups, t_chain_factors_plus)
 from repro.core.reconstruct import subset_slot_region
 from repro.engine.engine import ChainRegistry, EngineStats, ReleaseServing
-from repro.kernels.kron_matvec._layout import interpret_default
+from repro.kernels.kron_matvec._layout import resolve_use_kernel
 from repro.kernels.kron_matvec.fused import apply_epilogue, fused_chain_matvec
 from repro.kernels.kron_matvec.stats import CHAIN_STATS
 from repro.obs import TRACER
@@ -102,8 +102,7 @@ class PlusEngine(ReleaseServing, ChainRegistry):
                  precompile: bool = True, dtype=None):
         self.plan = plan
         self.schema = plan.schema
-        self.use_kernel = (not interpret_default()) if use_kernel is None \
-            else use_kernel
+        self.use_kernel = resolve_use_kernel(use_kernel)
         self.dtype = noise_dtype() if dtype is None else dtype
         self.stats = EngineStats()
         self._pos = {c: i for i, c in enumerate(plan.cliques)}

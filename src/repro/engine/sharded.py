@@ -26,11 +26,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.domain import Clique, Domain
 from repro.core.mechanism import Measurement, noise_dtype
 from repro.core.plantable import BasePlan
+from repro.kernels.kron_matvec._layout import resolve_use_kernel
 from repro.obs import REGISTRY
 
 # Process-wide engine-cache event feed for /metrics (per-cache ints stay on
@@ -229,22 +229,62 @@ def _clique_strides(domain: Domain, clique: Clique) -> Tuple[np.ndarray, int]:
     return strides, int(np.prod(sizes)) if clique else 1
 
 
+# Largest one-hot block (records × cells) one histogram step builds: 2^24
+# elements, 64 MiB at float32, whatever the record count.
+_ONE_HOT_ELEMS = 1 << 24
+
+
 def _local_marginal(records, cols, strides, n_cells, dtype=None):
-    """One-hot-matmul histogram of the clique columns (records: (N, n_attrs)).
+    """One-hot histogram of the clique columns (records: (N, n_attrs)).
 
     ``dtype=None`` resolves to :func:`repro.core.mechanism.noise_dtype` —
     the historical hard-coded float32 default silently capped histogram
     exactness at 2²⁴ counts per cell even when the engine path threaded
     float64 everywhere else.
     """
+    return _local_marginals(records, [(cols, strides, n_cells)], dtype)[0]
+
+
+def _local_marginals(records, metas, dtype=None):
+    """One-hot histograms of several cliques, ``metas[i] = (cols, strides,
+    n_cells)``, over the same records.
+
+    One scan consumes the records in chunks of ``_ONE_HOT_ELEMS //
+    max(n_cells)`` rows and builds every clique's table in its body, so no
+    ``(rows, n_cells)`` one-hot grows with N and the program holds one loop
+    whatever the clique count (a loop per clique, each with its own chunk
+    shape, made the TPU compile grow with N).  Rows past N in the last chunk
+    map to index -1, whose one-hot row is all zeros.
+    """
     dtype = noise_dtype() if dtype is None else dtype
-    if len(cols) == 0:
-        return jnp.asarray([records.shape[0]], dtype)
-    flat = jnp.zeros((records.shape[0],), jnp.int32)
-    for c, s in zip(cols, strides):
-        flat = flat + records[:, c] * int(s)
-    oh = jax.nn.one_hot(flat, n_cells, dtype=dtype)
-    return jnp.sum(oh, axis=0)
+    n = records.shape[0]
+    live = [m for m in metas if len(m[0])]
+    if not live:
+        return tuple(jnp.asarray([n], dtype) for _ in metas)
+    chunk = max(1, min(n, _ONE_HOT_ELEMS // max(m[2] for m in live)))
+    n_chunks = -(-n // chunk)
+    recs = jnp.pad(records, ((0, n_chunks * chunk - n), (0, 0)))
+    recs = recs.reshape(n_chunks, chunk, records.shape[1])
+
+    def step(acc, xs):
+        i, rec = xs
+        valid = i * chunk + jnp.arange(chunk) < n
+        out = []
+        for h, (cols, strides, n_cells) in zip(acc, live):
+            flat = jnp.zeros((chunk,), jnp.int32)
+            for c, s in zip(cols, strides):
+                flat = flat + rec[:, c] * int(s)
+            flat = jnp.where(valid, flat, -1)
+            out.append(h + jnp.sum(jax.nn.one_hot(flat, n_cells, dtype=dtype),
+                                   axis=0))
+        return tuple(out), None
+
+    hists, _ = jax.lax.scan(
+        step, tuple(jnp.zeros((m[2],), dtype) for m in live),
+        (jnp.arange(n_chunks), recs))
+    hists = iter(hists)
+    return tuple(next(hists) if len(m[0]) else jnp.asarray([n], dtype)
+                 for m in metas)
 
 
 def sharded_marginals(domain: Domain, cliques: Sequence[Clique],
@@ -257,34 +297,41 @@ def sharded_marginals(domain: Domain, cliques: Sequence[Clique],
     """
     dtype = noise_dtype() if dtype is None else dtype
     cliques = list(cliques)
+    outs = sharded_marginals_program(domain, cliques, mesh, dtype)(records)
+    return {c: o for c, o in zip(cliques, outs)}
+
+
+def sharded_marginals_program(domain: Domain, cliques: Sequence[Clique],
+                              mesh: Optional[Mesh], dtype):
+    """The jitted program behind :func:`sharded_marginals`: records in, one
+    table per clique out.  With a mesh it is a shard_map over the mesh's
+    data axes whose per-device tables are psum'd (replicated output)."""
+    cliques = list(cliques)
     meta = [(_clique_strides(domain, c)) for c in cliques]
 
-    if mesh is None:
-        return {c: _local_marginal(records, list(c), meta[i][0], meta[i][1],
-                                   dtype)
-                for i, c in enumerate(cliques)}
+    def local(rec):
+        return _local_marginals(
+            rec, [(list(c),) + m for c, m in zip(cliques, meta)], dtype)
 
+    if mesh is None:
+        return jax.jit(local)
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
+    axes = data_axes + tuple(a for a in mesh.axis_names
+                             if a not in data_axes)
+
     def body(rec):
-        outs = []
-        for i, c in enumerate(cliques):
-            h = _local_marginal(rec, list(c), meta[i][0], meta[i][1], dtype)
-            outs.append(jax.lax.psum(h, data_axes + tuple(
-                a for a in mesh.axis_names if a not in data_axes)))
-        return tuple(outs)
+        return tuple(jax.lax.psum(h, axes) for h in local(rec))
 
     in_spec = P(data_axes, None)
     out_specs = tuple(P() for _ in cliques)
-    fn = shard_map(body, mesh=mesh, in_specs=(in_spec,), out_specs=out_specs,
-                   check_rep=False)
-    outs = jax.jit(fn)(records)
-    return {c: o for c, o in zip(cliques, outs)}
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(in_spec,),
+                                 out_specs=out_specs, check_vma=False))
 
 
 def sharded_measure(plan: BasePlan, records: jnp.ndarray,
                     key: jax.Array, mesh: Optional[Mesh] = None,
-                    use_kernel: bool = False,
+                    use_kernel: Optional[bool] = None,
                     dtype=None, secure: bool = False,
                     digits: int = 4) -> Dict[Clique, Measurement]:
     """Distributed Algorithms 1/5 (and 3): sharded marginalization + transform.
@@ -296,7 +343,8 @@ def sharded_measure(plan: BasePlan, records: jnp.ndarray,
     (plan, path, dtype, secure).  ``dtype`` governs the marginal tables and
     the noise draws; ``None`` resolves to
     :func:`repro.core.mechanism.noise_dtype` (float64 under jax x64), so the
-    distributed path matches the core path's precision.
+    distributed path matches the core path's precision.  ``use_kernel=None``
+    resolves from the backend (Pallas on a TPU).
 
     ``secure=True`` serves the numerically secure release (Alg 3) through
     :class:`~repro.engine.discrete_engine.DiscreteEngine`: same sharded
@@ -305,6 +353,7 @@ def sharded_measure(plan: BasePlan, records: jnp.ndarray,
     (``digits`` sets the σ̄ rationalization).  Plans without an integer-query
     rotation (RP+) raise ``ValueError``.
     """
+    use_kernel = resolve_use_kernel(use_kernel)
     dtype = noise_dtype() if dtype is None else dtype
     margs = sharded_marginals(plan.domain, plan.cliques, records, mesh,
                               dtype=dtype)

@@ -16,14 +16,19 @@ import tempfile
 import threading
 from typing import Dict, Optional
 
-CACHE_VERSION = 1
+# Version 2: the fused chain became one dense-operator contraction, so the
+# VMEM footprint of every tuned config changed.
+CACHE_VERSION = 2
 
 
 def default_cache_dir() -> str:
+    """``$REPRO_AUTOTUNE_CACHE``, else ``.autotune_cache/`` at the checkout
+    root — never a directory outside the checkout (repro/runtime.py)."""
     env = os.environ.get("REPRO_AUTOTUNE_CACHE", "")
     if env:
         return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro", "autotune")
+    from repro.runtime import CHECKOUT_ROOT
+    return str(CHECKOUT_ROOT / ".autotune_cache")
 
 
 def _slug(device_kind: str) -> str:

@@ -4,9 +4,11 @@ kernel (docs/DESIGN.md §14, docs/TUNING.md).
 For each chain signature group — same per-axis factor shapes, epilogue and
 (padded) batch — the tuner enumerates a small candidate lattice of
 ``(block_l, compute_dtype)`` launch configs, scores each with the analytic
-roofline cost model (:class:`repro.roofline.cost_model.CostModel`), compares
-the best fused candidate against the modeled per-axis fallback, and caches
-the winner.  ``REPRO_KERNEL_AUTOTUNE`` selects the mode:
+roofline cost model (:class:`repro.roofline.cost_model.CostModel`), and
+caches the winner.  Whether the chain fuses at all is not the tuner's
+choice: it is the batch-independent footprint rule
+(:func:`repro.kernels.kron_matvec.fused.chain_fuses`), so a chain rounds
+the same way whatever batch it rides in.  ``REPRO_KERNEL_AUTOTUNE`` selects the mode:
 
 * ``off``     — fixed untuned defaults everywhere (the pre-tuner behavior);
 * ``model``   — analytic pick only (the default; zero kernel launches);
@@ -39,14 +41,12 @@ from repro.roofline.cost_model import CostModel, DeviceSpec, detect_device
 
 from .cache import TuningCache
 
-# Fused must beat the modeled per-axis fallback by this margin before the
-# tuner abandons the one-pad/one-call contract — near-ties keep the fused
-# path (its stats contract is what the engine tier is built around).
-_FALLBACK_MARGIN = 0.9
-
 # measure mode: number of analytically best candidates to time for real.
 _MEASURE_TOP_K = 3
 _MEASURE_REPS = 3
+
+# Largest fused row block the tuner offers on a real chip.
+_MAX_CHIP_BLOCK_L = 512
 
 
 @dataclass(frozen=True)
@@ -56,7 +56,7 @@ class TunedConfig:
     block_l: int
     vmem_budget: int
     compute_dtype: str = "float32"
-    fused: bool = True               # False: per-axis fallback predicted faster
+    fused: bool = True               # False: the footprint rule says per-axis
     predicted_s: float = 0.0
     intensity: float = 0.0           # predicted flops / HBM byte
     grid_steps: int = 0
@@ -151,7 +151,8 @@ def tune_chain(factors: Sequence, dims: Sequence[int], batch: int = 1,
     narrowing; see module docstring).  ``mode`` overrides the env mode —
     ``resolve_config`` passes ``"model"`` for on-the-fly misses.
     """
-    from repro.kernels.kron_matvec.fused import _SUBLANE, plan_chain
+    from repro.kernels.kron_matvec.fused import (_SUBLANE, chain_fuses,
+                                                  plan_chain)
 
     dev = detect_device() if device is None else device
     mode = autotune_mode() if mode is None else mode
@@ -161,12 +162,15 @@ def tune_chain(factors: Sequence, dims: Sequence[int], batch: int = 1,
     epi = tuple(epilogue) if epilogue is not None else (None,) * len(dims)
     key = chain_key(dev.kind, dims, fshapes, epi, batch)
 
-    # A batch large enough that even one grid row overflows VMEM caps the
-    # exact-batch candidate; 2**16 rows is far past any signature group.
+    # Interpret mode pays per grid step, so any block up to the exact padded
+    # batch is a candidate (2**16 rows is far past any signature group).  On
+    # a chip Mosaic unrolls a block's contraction, so blocks past 512 rows
+    # only add compile time once the step overhead is amortized.
+    max_rows = 2 ** 16 if dev.interpret else _MAX_CHIP_BLOCK_L
     scored = []   # (cost, plan)
     for dt in _dtype_candidates(dtypes):
         sub = _SUBLANE.get(dt, 8)
-        for bl in _block_lattice(batch, sub, max_exact=2 ** 16):
+        for bl in _block_lattice(batch, sub, max_exact=max_rows):
             plan = plan_chain(factors, dims, batch=batch, block_l=bl,
                               vmem_budget=dev.vmem_limit, epilogue=epi,
                               compute_dtype=dt)
@@ -175,7 +179,10 @@ def tune_chain(factors: Sequence, dims: Sequence[int], batch: int = 1,
             scored.append((model.chain_cost(plan, batch), plan))
 
     per_axis_s = model.per_axis_cost(dims, fshapes, batch)
-    if not scored:
+    fuses = bool(scored) and chain_fuses(
+        factors, dims, epi, scored[0][1].compute_dtype,
+        vmem_budget=dev.default_vmem_budget)
+    if not fuses:
         from repro.kernels.kron_matvec._layout import pad_to
         cfg = TunedConfig(block_l=min(128, pad_to(max(batch, 1), 8)),
                           vmem_budget=dev.default_vmem_budget,
@@ -191,22 +198,13 @@ def tune_chain(factors: Sequence, dims: Sequence[int], batch: int = 1,
         best_cost, best_plan = _refine_by_timing(
             scored[:_MEASURE_TOP_K], factors, dims, batch, epi, interpret)
 
-    if per_axis_s < _FALLBACK_MARGIN * best_cost.predicted_s:
-        cfg = TunedConfig(block_l=best_plan.block_l,
-                          vmem_budget=best_plan.vmem_bytes,
-                          compute_dtype=best_plan.compute_dtype, fused=False,
-                          predicted_s=per_axis_s,
-                          intensity=best_cost.intensity,
-                          grid_steps=best_cost.grid_steps,
-                          source="measure" if mode == "measure" else "model")
-    else:
-        cfg = TunedConfig(block_l=best_plan.block_l,
-                          vmem_budget=best_plan.vmem_bytes,
-                          compute_dtype=best_plan.compute_dtype, fused=True,
-                          predicted_s=best_cost.predicted_s,
-                          intensity=best_cost.intensity,
-                          grid_steps=best_cost.grid_steps,
-                          source="measure" if mode == "measure" else "model")
+    cfg = TunedConfig(block_l=best_plan.block_l,
+                      vmem_budget=best_plan.vmem_bytes,
+                      compute_dtype=best_plan.compute_dtype, fused=True,
+                      predicted_s=best_cost.predicted_s,
+                      intensity=best_cost.intensity,
+                      grid_steps=best_cost.grid_steps,
+                      source="measure" if mode == "measure" else "model")
     _REGISTRY[key] = cfg
     if persist:
         TuningCache(dev.kind).put(key, cfg.as_dict())

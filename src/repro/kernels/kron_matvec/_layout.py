@@ -18,6 +18,13 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def resolve_use_kernel(use_kernel: Optional[bool]) -> bool:
+    """``None`` → the backend's own chain path: the Pallas kernels on a TPU,
+    batched jnp elsewhere (interpret-mode Pallas only adds Python
+    overhead there).  Every serving entry point resolves it here."""
+    return (not interpret_default()) if use_kernel is None else bool(use_kernel)
+
+
 def pad_to(x: int, m: int) -> int:
     return -(-x // m) * m
 

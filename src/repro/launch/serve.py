@@ -29,6 +29,7 @@ from repro.core import all_kway, select
 from repro.data.tabular import (adult_domain, marginals_from_records,
                                 synthetic_records)
 from repro.obs import TRACER
+from repro.runtime import enable_compile_cache
 from repro.serve import (BudgetLedger, ReleaseRequest, ReleaseServer,
                          start_stats_http)
 
@@ -93,6 +94,7 @@ def main() -> None:
                          "(render with tools/repro_trace.py)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     if args.trace:
         TRACER.enable(args.trace)
     server = build_server(args.ledger, args.tenants, rho=args.rho,

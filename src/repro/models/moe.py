@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .layers import PDef
 from .sharding import batch_axis_names, current_mesh, logical
@@ -219,12 +218,12 @@ def moe_apply(p, x, *, cfg) -> Tuple[jnp.ndarray, jnp.ndarray]:
     seq_shardable = x.shape[1] % n_shards == 0
     body = _moe_local if seq_shardable else _moe_replicated_local
     x_spec = P(batch_axes, "model" if seq_shardable else None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(body, cfg=cfg, n_shards=n_shards, e_loc=e_loc,
                 axis="model", data_axes=data_axes,
                 all_axes=tuple(mesh.axis_names)),
         mesh=mesh,
         in_specs=({k: pspecs[k] for k in p}, x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False)
+        check_vma=False)
     return fn(p, x)

@@ -37,8 +37,8 @@ class DeviceSpec:
     ``peak_flops`` is the narrow-dtype (bf16) MXU peak; ``peak_flops_f32``
     the fp32 peak.  ``vmem_limit`` is the hard ceiling a fused working tile
     may occupy; ``default_vmem_budget`` is the conservative *untuned* budget
-    (the historical 4 MiB stays the CPU/interpret fallback so default plans
-    are unchanged).  ``step_overhead_s`` is the per-grid-step launch cost —
+    (the CPU/interpret row uses the v5e default so interpret-mode plans
+    match the chip's).  ``step_overhead_s`` is the per-grid-step launch cost —
     microseconds on real hardware, milliseconds for the Python interpreter.
     """
 
@@ -51,79 +51,89 @@ class DeviceSpec:
     default_vmem_budget: int     # untuned plan_chain budget
     step_overhead_s: float       # per grid-step launch overhead
     interpret: bool = False      # Pallas interpret mode (kernel body in Python)
+    source: str = ""             # where the peaks come from
 
     def peak_for(self, compute_dtype: str) -> float:
         return self.peak_flops_f32 if compute_dtype == "float32" \
             else self.peak_flops
 
 
-# Known device kinds (``jax.devices()[0].device_kind``), matched by
-# normalized substring.  TPU VMEM is ~16 MiB/core on v4/v5e (pallas guide);
-# budgets leave headroom for the compiler's own temporaries.
+# Known device kinds (``jax.devices()[0].device_kind``, lower-cased).  The
+# bf16 peak and HBM bandwidth of each TPU row are the published per-chip
+# figures named in ``source``; no fp32 MXU peak is published, so
+# ``peak_flops_f32`` is an assumed half of the bf16 peak.  ``ici_bw`` and
+# ``step_overhead_s`` are assumptions too.  TPU VMEM budgets leave headroom
+# under the 16 MiB default scoped limit (Pallas TPU docs).  A kind that is not
+# here is an error (:func:`device_spec`): specs are never guessed.
 DEVICE_TABLE = {
     "cpu": DeviceSpec("cpu", peak_flops=2e11, peak_flops_f32=1e11,
                       hbm_bw=5e10, ici_bw=1e10,
-                      vmem_limit=256 * _MIB, default_vmem_budget=4 * _MIB,
-                      step_overhead_s=2e-3, interpret=True),
+                      vmem_limit=256 * _MIB, default_vmem_budget=8 * _MIB,
+                      step_overhead_s=2e-3, interpret=True,
+                      source="assumed host figures (interpret mode)"),
     "tpu v4": DeviceSpec("tpu v4", peak_flops=275e12, peak_flops_f32=137e12,
                          hbm_bw=1228e9, ici_bw=50e9,
                          vmem_limit=16 * _MIB, default_vmem_budget=8 * _MIB,
-                         step_overhead_s=2e-6),
+                         step_overhead_s=2e-6,
+                         source="Google Cloud, 'TPU v4': 275 TFLOP/s bf16, "
+                                "1228 GB/s, 32 GiB HBM"),
     "tpu v5 lite": DeviceSpec("tpu v5 lite", peak_flops=197e12,
                               peak_flops_f32=98e12,
                               hbm_bw=819e9, ici_bw=50e9,
                               vmem_limit=16 * _MIB,
                               default_vmem_budget=8 * _MIB,
-                              step_overhead_s=2e-6),
+                              step_overhead_s=2e-6,
+                              source="Google Cloud, 'TPU v5e': 197 TFLOP/s "
+                                     "bf16, 819 GB/s, 16 GB HBM"),
     "tpu v5p": DeviceSpec("tpu v5p", peak_flops=459e12, peak_flops_f32=229e12,
                           hbm_bw=2765e9, ici_bw=100e9,
                           vmem_limit=16 * _MIB, default_vmem_budget=8 * _MIB,
-                          step_overhead_s=2e-6),
+                          step_overhead_s=2e-6,
+                          source="Google Cloud, 'TPU v5p': 459 TFLOP/s bf16, "
+                                 "2765 GB/s, 95 GB HBM"),
     "tpu v6 lite": DeviceSpec("tpu v6 lite", peak_flops=918e12,
                               peak_flops_f32=459e12,
                               hbm_bw=1640e9, ici_bw=100e9,
                               vmem_limit=32 * _MIB,
                               default_vmem_budget=16 * _MIB,
-                              step_overhead_s=2e-6),
-    "gpu": DeviceSpec("gpu", peak_flops=1e14, peak_flops_f32=5e13,
-                      hbm_bw=2e12, ici_bw=9e11,
-                      vmem_limit=16 * _MIB, default_vmem_budget=4 * _MIB,
-                      step_overhead_s=5e-6),
+                              step_overhead_s=2e-6,
+                              source="Google Cloud, 'TPU v6e': 918 TFLOP/s "
+                                     "bf16, 1640 GB/s, 32 GB HBM"),
 }
 
 _ALIASES = {"tpu v5e": "tpu v5 lite", "tpu v5litepod": "tpu v5 lite",
-            "tpu v6e": "tpu v6 lite"}
+            "tpu v5": "tpu v5p", "tpu v6e": "tpu v6 lite"}
 
 
 def device_spec(kind: str) -> DeviceSpec:
-    """Best-match :class:`DeviceSpec` for a ``device_kind`` string."""
+    """The :class:`DeviceSpec` of a ``device_kind`` string.
+
+    Raises ``KeyError`` for a kind absent from :data:`DEVICE_TABLE`: the
+    cost model, the tuner and the VMEM budgets must never run on another
+    device's numbers.
+    """
     k = kind.strip().lower()
     k = _ALIASES.get(k, k)
-    if k in DEVICE_TABLE:
-        return DEVICE_TABLE[k]
-    for name, spec in DEVICE_TABLE.items():
-        if name != "cpu" and name in k:
-            return spec
-    if "tpu" in k:        # unknown TPU generation: v5e-ish conservative specs
-        return DEVICE_TABLE["tpu v5 lite"]
-    if "gpu" in k or "cuda" in k or "rocm" in k:
-        return DEVICE_TABLE["gpu"]
-    return DEVICE_TABLE["cpu"]
+    if k not in DEVICE_TABLE:
+        raise KeyError(f"device kind {kind!r} is not in DEVICE_TABLE "
+                       f"(known: {sorted(DEVICE_TABLE)}); add a row with the "
+                       f"source of its peaks")
+    return DEVICE_TABLE[k]
 
 
 _DETECTED: Optional[DeviceSpec] = None
 
 
 def detect_device(refresh: bool = False) -> DeviceSpec:
-    """DeviceSpec of the runtime's default jax device (cached per process)."""
+    """DeviceSpec of the runtime's default jax device (cached per process).
+
+    Errors propagate: a backend that cannot list its devices, or a device
+    kind the table does not know, raises instead of planning on CPU specs.
+    """
     global _DETECTED
     if _DETECTED is None or refresh:
-        try:
-            import jax
-            kind = jax.devices()[0].device_kind
-        except Exception:                      # pragma: no cover - no backend
-            kind = "cpu"
-        _DETECTED = device_spec(kind)
+        import jax
+        _DETECTED = device_spec(jax.devices()[0].device_kind)
     return _DETECTED
 
 
@@ -166,48 +176,34 @@ class CostModel:
         self.device = detect_device() if device is None else device
 
     # ------------------------------------------------------------ fused chain
-    def chain_flops(self, in_dims: Sequence[int],
-                    fshapes: Sequence[Optional[Tuple[int, int]]],
-                    epilogue: Sequence[Optional[str]] = ()) -> float:
-        """MXU FLOPs for ONE batch row of the chain (2·m·n per contraction,
-        times the surrounding free dims; cumsum epilogues contract with the
-        (n, n) triangular operand)."""
-        cur = list(in_dims)
-        flops = 0.0
-        for axis, spec in enumerate(fshapes):
-            if spec is None:
-                continue
-            m, n = spec
-            others = math.prod(cur) // cur[axis]
-            flops += 2.0 * m * n * others
-            cur[axis] = m
-        for axis, op in enumerate(epilogue or ()):
-            if op == "cumsum":
-                n = cur[axis]
-                flops += 2.0 * n * n * (math.prod(cur) // n)
-        return flops
-
     def chain_cost(self, plan, batch: int) -> ChainCost:
         """Cost of one fused launch of ``plan`` (a ChainPlan) at ``batch``.
 
-        HBM traffic: the zero-pad materialization + kernel read of the input
-        tile, the factor loads (once — they stay VMEM-resident across grid
-        steps), the kernel write + slice-back of the output.  All widths are
-        the *padded* widths: padding waste is a real cost the tuner must see,
-        which is what stops it from rounding a 2280-row batch up to a
-        4096-row power of two.
+        The fused kernel is a tiled matmul of the padded ``(B_p, W_in)``
+        stack with the dense ``(W_in, W_out)`` chain operator, so its MXU
+        work is ``2 · W_in · W_out`` FLOPs per padded row over a grid of
+        (row, output-column, contraction) blocks.  HBM traffic: the zero-pad
+        write, the operator build, one read of each input block per output-
+        column block and of each operator block per row block, and the
+        output write + slice-back.  All widths are the *padded* widths:
+        padding waste is a real cost the tuner must see, which is what stops
+        it from rounding a 2280-row batch up to a 4096-row power of two.
         """
         dev = self.device
         isz = _itemsize(plan.compute_dtype)
         b_p = _pad_to(max(batch, 1), plan.block_l)
-        steps = b_p // plan.block_l
-        factor_bytes = sum(m * n * isz for s in plan.fshapes
-                           if s is not None for m, n in [s])
-        in_bytes = 2.0 * b_p * plan.w_in * isz          # pad write + read
+        row_blocks = b_p // plan.block_l
+        col_blocks = plan.w_out // plan.block_n
+        steps = row_blocks * col_blocks * (plan.w_in // plan.block_k)
+        op_bytes = plan.w_in * plan.w_out * isz
+        # A whole-operator block keeps its index across the grid and is read
+        # once; lane-tiled operator blocks are read again per row block.
+        whole = plan.block_k == plan.w_in and plan.block_n == plan.w_out
+        operator_bytes = op_bytes * (2.0 if whole else 1.0 + row_blocks)
+        in_bytes = b_p * plan.w_in * isz * (1.0 + col_blocks)  # pad + reads
         out_bytes = 2.0 * b_p * plan.w_out * 4          # write + slice (fp32)
-        hbm = in_bytes + out_bytes + factor_bytes
-        flops = self.chain_flops(plan.in_dims, plan.fshapes,
-                                 plan.epilogue) * b_p
+        hbm = in_bytes + out_bytes + operator_bytes
+        flops = 2.0 * plan.w_in * plan.w_out * b_p
         t_c = flops / dev.peak_for(plan.compute_dtype)
         t_m = hbm / dev.hbm_bw
         t_o = steps * dev.step_overhead_s
@@ -222,8 +218,10 @@ class CostModel:
                       fshapes: Sequence[Optional[Tuple[int, int]]],
                       batch: int) -> float:
         """Predicted seconds for the per-axis fallback path: one pad → HBM
-        round-trip → slice per non-trivial factor, with the per-axis kernel's
-        own (8 × 512) grid blocking driving the step count."""
+        round-trip → slice per non-trivial factor.  Each axis runs on the
+        ``(lead, n, cols)`` layout of ops.py — ``(L, n, R)``, or
+        ``(1, n, L·R)`` after a rotation when ``R < 128`` (which adds a
+        transpose each way) — with up-to-8-row × 512-lane blocks."""
         dev = self.device
         cur = list(in_dims)
         total = 0.0
@@ -233,12 +231,18 @@ class CostModel:
             m, n = spec
             left = max(batch, 1) * (math.prod(cur[:axis]) if axis else 1)
             right = math.prod(cur[axis + 1:]) if axis + 1 < len(cur) else 1
-            l_p, r_p = _pad_to(left, 8), _pad_to(right, 512)
+            rotate = right < 128
+            lead, cols = (1, left * right) if rotate else (left, right)
+            bl = min(8, lead)
+            l_p, c_p = _pad_to(lead, bl), _pad_to(cols, 128)
             n_p, m_p = _pad_to(n, 8), _pad_to(m, 8)
-            in_b = 2.0 * l_p * n_p * r_p * 4
-            out_b = 2.0 * l_p * m_p * r_p * 4
-            flops = 2.0 * m * n * left * right
-            steps = (l_p // 8) * (r_p // 512)
+            in_b = 2.0 * l_p * n_p * c_p * 4
+            out_b = 2.0 * l_p * m_p * c_p * 4
+            if rotate:                       # transpose in and back out
+                in_b += 2.0 * left * n * right * 4
+                out_b += 2.0 * left * m * right * 4
+            flops = 2.0 * m_p * n_p * l_p * c_p
+            steps = (l_p // bl) * -(-c_p // 512)
             total += max(flops / dev.peak_flops_f32,
                          (in_b + out_b) / dev.hbm_bw) \
                 + steps * dev.step_overhead_s

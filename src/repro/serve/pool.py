@@ -21,6 +21,7 @@ from typing import Dict, Optional
 
 from repro.core.mechanism import noise_dtype
 from repro.engine.sharded import _EngineCache
+from repro.kernels.kron_matvec._layout import resolve_use_kernel
 
 
 class EnginePool:
@@ -41,9 +42,11 @@ class EnginePool:
             lambda: defaultdict(int))                  # guarded-by: _lock
         self._tenant_total: Dict[str, int] = defaultdict(int)  # guarded-by: _lock
 
-    def engine_for(self, tenant: str, plan, use_kernel: bool = False,
+    def engine_for(self, tenant: str, plan, use_kernel: Optional[bool] = None,
                    dtype=None, secure: bool = False, digits: int = 4):
-        """Cached compiled engine for ``plan``, accounted to ``tenant``."""
+        """Cached compiled engine for ``plan``, accounted to ``tenant``
+        (``use_kernel=None``: the backend's own chain path)."""
+        use_kernel = resolve_use_kernel(use_kernel)
         dtype = noise_dtype() if dtype is None else dtype
         with self._lock:
             eng = self.cache.get(plan, use_kernel, dtype, secure, digits)

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import contextlib
 import json
+import logging
 import queue
 import threading
 import time
@@ -45,12 +46,15 @@ from repro.core.accountant import BudgetExhausted
 from repro.core.domain import Clique
 from repro.core.mechanism import noise_dtype, pcost_of_plan
 from repro.engine.multi import can_fuse, measure_multi
+from repro.kernels.kron_matvec._layout import resolve_use_kernel
 from repro.obs import REGISTRY, TRACER, exposition
 from repro.serve.ledger import BudgetLedger, UnknownTenant
 from repro.serve.pool import EnginePool
 from repro.serve.stats import ServerStats
 
 RELEASE_KINDS = ("marginal", "range")
+
+_LOG = logging.getLogger(__name__)
 
 
 @dataclass
@@ -119,15 +123,16 @@ class ReleaseServer:
     max_batch:    worker drain size; 1 disables cross-tenant fusion.
     max_wait_ms:  how long the worker lingers after the first request to let
                   a batch fill (0 = serve whatever is already queued).
-    use_kernel:   route fused chains through the Pallas kernel (TPU) or the
-                  batched-jnp path (CPU default).
+    use_kernel:   route chains through the Pallas kernels or the batched-jnp
+                  path; ``None`` (default) resolves from the backend —
+                  Pallas on a TPU, batched jnp elsewhere.
     pool:         engine warm pool; default ``EnginePool()`` (capacity from
                   ``REPRO_ENGINE_CACHE_SIZE``).
     noise_seed:   base key for server-assigned per-request noise keys.
     """
 
     def __init__(self, ledger: BudgetLedger, max_batch: int = 16,
-                 max_wait_ms: float = 2.0, use_kernel: bool = False,
+                 max_wait_ms: float = 2.0, use_kernel: Optional[bool] = None,
                  dtype=None, pool: Optional[EnginePool] = None,
                  noise_seed: int = 0):
         if max_batch < 1:
@@ -135,7 +140,7 @@ class ReleaseServer:
         self.ledger = ledger
         self.max_batch = int(max_batch)
         self.max_wait_ms = float(max_wait_ms)
-        self.use_kernel = bool(use_kernel)
+        self.use_kernel = resolve_use_kernel(use_kernel)
         self.dtype = noise_dtype() if dtype is None else dtype
         self.pool = EnginePool() if pool is None else pool
         self.stats = ServerStats()
@@ -435,7 +440,10 @@ class ReleaseServer:
                 # strand already-charged futures: fall back to the solo path
                 # (p.measurements stays None), where a genuinely bad request
                 # fails alone in phase 3 and the rest of the batch serves.
-                pass
+                # Never silently: every fallback is counted and logged.
+                self.stats.record_fused_fallback()
+                _LOG.exception("fused launch for %d requests failed; "
+                               "serving them on the solo path", len(fusable))
             else:
                 t_fuse1 = time.monotonic()
                 sigs = set()

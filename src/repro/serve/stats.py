@@ -19,6 +19,8 @@ Metric names:
   p50/p99 are computed over the ring on demand, exactly as /stats always did.
 * ``repro_serve_batches_total``, ``repro_serve_batched_launch_groups_total``,
   ``repro_serve_queue_depth`` (gauge), ``repro_serve_queue_depth_max``.
+* ``repro_serve_fused_fallbacks_total`` — fused multi-request launches that
+  raised and were served request by request instead.
 
 Mutation comes from the worker thread plus the submit path while the /stats
 and /metrics HTTP threads read; every cell is an atomic counter, and queue
@@ -159,6 +161,10 @@ class ServerStats:
             "repro_serve_queue_depth", "Requests currently queued")
         self._depth_max = self.registry.gauge(
             "repro_serve_queue_depth_max", "Queue-depth high-water mark")
+        self._fused_fallbacks = self.registry.counter(
+            "repro_serve_fused_fallbacks_total",
+            "Fused multi-request launches that failed and were served on "
+            "the solo path")
 
     # -- legacy field views ---------------------------------------------
     @property
@@ -168,6 +174,13 @@ class ServerStats:
     @property
     def batched_launch_groups(self) -> int:
         return int(self._groups.value)
+
+    @property
+    def fused_fallbacks(self) -> int:
+        return int(self._fused_fallbacks.value)
+
+    def record_fused_fallback(self) -> None:
+        self._fused_fallbacks.inc()
 
     @property
     def queue_depth(self) -> int:
@@ -211,6 +224,7 @@ class ServerStats:
             "batches": batches,
             "batch_occupancy": occ,
             "batched_launch_groups": self.batched_launch_groups,
+            "fused_fallbacks": self.fused_fallbacks,
             "queue_depth": self.queue_depth,
             "queue_depth_max": self.queue_depth_max,
             "tenants": {t: s.to_dict() for t, s in tenants.items()},

@@ -137,8 +137,11 @@ def test_tril_epilogue_accounted_at_compute_dtype():
                         compute_dtype="bfloat16")
     epibf = plan_chain(facs, (4,), batch=16, block_l=16,
                        epilogue=("cumsum",), compute_dtype="bfloat16")
-    assert epi32.vmem_bytes - base32.vmem_bytes == 4 * 4 * 4
-    assert epibf.vmem_bytes - basebf.vmem_bytes == 2 * 4 * 4
+    # The cumsum folds into the dense chain operator, which is already
+    # accounted at the compute dtype: the epilogue adds no VMEM of its own.
+    assert epi32.vmem_bytes == base32.vmem_bytes
+    assert epibf.vmem_bytes == basebf.vmem_bytes
+    assert basebf.vmem_bytes < base32.vmem_bytes
 
 
 # ------------------------------------------------------------------ cost model

@@ -82,3 +82,39 @@ def test_dryrun_artifacts_complete_and_coherent():
             n_skip += 1
             assert "full-attention" in rec["reason"]
     assert n_ok == 64 and n_skip == 16
+
+
+# ----------------------------------------------------------- device table
+def test_device_table_rows_name_their_source():
+    from repro.roofline.cost_model import DEVICE_TABLE
+    assert "gpu" not in DEVICE_TABLE
+    v5e = DEVICE_TABLE["tpu v5 lite"]
+    assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
+    for kind, spec in DEVICE_TABLE.items():
+        assert spec.source, kind
+
+
+@pytest.mark.parametrize("kind", ["TPU v5 lite", "tpu v5e", "TPU v4", "cpu"])
+def test_device_spec_known_kinds(kind):
+    from repro.roofline.cost_model import device_spec
+    assert device_spec(kind).kind in kind.lower() or kind == "tpu v5e"
+
+
+@pytest.mark.parametrize("kind", ["TPU v99", "NVIDIA H100", "tpu"])
+def test_device_spec_unknown_kind_raises(kind):
+    from repro.roofline.cost_model import device_spec
+    with pytest.raises(KeyError):
+        device_spec(kind)
+
+
+def test_detect_device_raises_when_backend_fails(monkeypatch):
+    from repro.roofline import cost_model
+
+    def broken():
+        raise RuntimeError("no backend")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError):
+        cost_model.detect_device(refresh=True)
+    monkeypatch.undo()
+    assert cost_model.detect_device(refresh=True).kind == "cpu"

@@ -76,7 +76,10 @@ def test_measure_multi_rejects_unfusable_plans():
 
 def test_cross_tenant_batching_shares_chain_launches(tmp_path, monkeypatch):
     """Two same-signature tenants in one batch ride the SAME chain launches
-    (kernel-launch counter): fused launches == launches for one tenant."""
+    (kernel-launch counter): fused launches == launches for one tenant.
+
+    A batch runs as one compiled program, so launches are counted while it
+    is traced: the program cache is cleared before each counted batch."""
     calls = {"n": 0}
     real = multi_mod.kron_matvec_batched
 
@@ -90,11 +93,13 @@ def test_cross_tenant_batching_shares_chain_launches(tmp_path, monkeypatch):
     keys = [jax.random.PRNGKey(7), jax.random.PRNGKey(8)]
 
     calls["n"] = 0
+    multi_mod._flat_program.cache_clear()
     measure_multi([(plans[0], margs[0], keys[0])])
     solo_launches = calls["n"]
     assert solo_launches == 2            # signatures (5,) and (5,5)
 
     calls["n"] = 0
+    multi_mod._flat_program.cache_clear()
     measure_multi(list(zip(plans, margs, keys)))
     assert calls["n"] == solo_launches   # second tenant rides along free
 
@@ -107,6 +112,7 @@ def test_cross_tenant_batching_shares_chain_launches(tmp_path, monkeypatch):
                                           seed=i))
                 for i in range(2)]
         calls["n"] = 0
+        multi_mod._flat_program.cache_clear()
         srv.resume()
         res = [f.result(120) for f in futs]
         assert calls["n"] == solo_launches
