@@ -36,6 +36,13 @@ class Measurement:
     sigma2: float
 
 
+def host_bytes(args) -> int:
+    """Bytes a program call copies to the device: the numpy leaves of its
+    arguments (device-resident operands are not copied again)."""
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(args)
+               if isinstance(x, np.ndarray))
+
+
 def pcost_of_plan(plan: Plan) -> float:
     """Total privacy cost Σ_A p_A / σ²_A (Thm 3)."""
     return sum(p_coeff(plan.domain, c) / s for c, s in plan.sigmas.items())

@@ -12,7 +12,6 @@ mutually consistent.  The per-axis factors of U_{A←A'} are:
 from __future__ import annotations
 
 import math
-import time
 from functools import lru_cache
 from typing import Dict, List, Mapping, Optional, Sequence
 
@@ -21,9 +20,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.obs import TRACER
+
 from .domain import Clique, Domain, subsets
 from .kron import kron_matvec, kron_matvec_np
-from .mechanism import Measurement
+from .mechanism import Measurement, host_bytes
 from .residual import sub_pinv, variance_coeff
 from .select import Plan
 
@@ -168,32 +169,45 @@ def reconstruct_all_batched(plan: Plan, measurements: Mapping[Clique, Measuremen
             for c in group:
                 out[c] = np.asarray(measurements[()].omega, dtype=float).reshape(-1)
             continue
-        x = np.stack([embed_subset_answers(plan, measurements, c).reshape(-1)
-                      for c in group])
-        factors = u_chain_factors(plan.domain, group[0])
-        launch = None
-        if use_kernel:
-            # Reconstruction carries no noise lanes: a tuned narrow compute
-            # dtype (fp32 accumulation) may serve it (docs/DESIGN.md §14).
-            launch = prepare_chain(factors, sizes, len(group),
-                                   allow_narrow=True)
-            operands = launch.operands
-            x = x.astype(np.float32)
-            if not launch.fused:          # the per-axis chain takes it N-D
-                x = x.reshape((len(group),) + tuple(sizes))
-        else:
-            operands = tuple(jnp.asarray(f) for f in factors)
+        dims = "x".join(map(str, sizes))
+        with TRACER.span("reconstruct.prepare").set(dims=dims):
+            factors = u_chain_factors(plan.domain, group[0])
+            launch = None
+            if use_kernel:
+                # Reconstruction carries no noise lanes: a tuned narrow
+                # compute dtype (fp32 accumulation) may serve it
+                # (docs/DESIGN.md §14).
+                launch = prepare_chain(factors, sizes, len(group),
+                                       allow_narrow=True)
+                operands = launch.operands
+            else:
+                operands = tuple(jnp.asarray(f) for f in factors)
+        with TRACER.span("reconstruct.embed").set(dims=dims,
+                                                  rows=len(group)):
+            x = np.stack([embed_subset_answers(plan, measurements, c)
+                          .reshape(-1) for c in group])
+            if launch is not None:
+                x = x.astype(np.float32)
+                if not launch.fused:      # the per-axis chain takes it N-D
+                    x = x.reshape((len(group),) + tuple(sizes))
         specs.append((tuple(sizes), launch))
         args.append((x, operands))
         groups.append(group)
     if not specs:
         return out
-    t0 = time.monotonic()
-    ys = _reconstruct_program(tuple(specs))(tuple(args))
+    args = tuple(args)
+    with TRACER.span("reconstruct.dispatch") as sp:
+        ys = _reconstruct_program(tuple(specs))(args)
+        if sp:
+            sp.set(bytes=host_bytes(args))
+    with TRACER.span("reconstruct.fetch") as sp:
+        ys = [np.asarray(y) for y in ys]
+        if sp:
+            sp.set(bytes=sum(y.nbytes for y in ys))
     for (sizes, launch), group, y in zip(specs, groups, ys):
         if launch is not None:
-            launch.record(len(group), t0)
-        y = np.asarray(y).reshape(len(group), -1)
+            launch.record(len(group))
+        y = y.reshape(len(group), -1)
         for i, c in enumerate(group):
             out[c] = y[i]
     return out

@@ -175,9 +175,7 @@ class ChainRegistry:
         """Export the chain's roofline predictions as gauges.
 
         Predicted arithmetic intensity, VMEM footprint, and runtime
-        (roofline/cost_model.py) sit next to the measured
-        ``repro_kernel_launch_seconds`` histogram under the same ``chain``
-        label, so predicted-vs-measured drift is a single /metrics query.
+        (roofline/cost_model.py), each under the chain's ``chain`` label.
         """
         try:
             from repro.obs.naming import chain_label

@@ -23,7 +23,6 @@ per-request through their cached engines by the caller.
 from __future__ import annotations
 
 import math
-import time
 from collections import defaultdict
 from dataclasses import dataclass, replace
 from functools import lru_cache
@@ -36,7 +35,7 @@ import jax.numpy as jnp
 
 from repro.core.domain import Clique
 from repro.core.kron import kron_matvec_batched
-from repro.core.mechanism import Measurement, noise_dtype
+from repro.core.mechanism import Measurement, host_bytes, noise_dtype
 from repro.core.residual import sub_matrix
 from repro.core.select import Plan
 from repro.kernels.kron_matvec._layout import resolve_use_kernel
@@ -128,16 +127,18 @@ def _flat_program(specs: Tuple[_GroupSpec, ...], widths: Tuple[int, ...],
 
     One program per batch layout instead of one per group: a release plan
     has hundreds of signature groups, and each program costs compile time.
+    A per-axis group's argument is empty: program 2 takes its lanes.
     """
     def run(keys, args):
         noise = dict(zip(widths, _draw_noise(widths, dtype, keys)))
         outs = []
-        for spec, (v, sig, operands) in zip(specs, args):
+        for spec, arg in zip(specs, args):
             z = noise[_noise_row(spec.m)][spec.row:spec.row + spec.g_pad,
                                           :spec.m]
             if spec.nd:
                 outs.append(z)
                 continue
+            v, sig, operands = arg
             x = jnp.concatenate([v.astype(dtype), z], axis=0)
             if spec.dims:
                 x = (spec.launch.apply(operands, x) if spec.launch is not None
@@ -193,11 +194,12 @@ def measure_batch(items: Sequence[MultiItem], use_kernel: Optional[bool],
     # Keys are pulled host-side once per item; per-lane jax-array indexing
     # would pay one dispatch per lane.
     groups: Dict[tuple, List[tuple]] = defaultdict(list)
-    for i, (plan, _margs, key) in enumerate(items):
-        keys = np.asarray(jax.random.split(key, len(plan.cliques)))
-        for pos, c in enumerate(plan.cliques):
-            dims = tuple(plan.domain.attributes[a].size for a in c)
-            groups[dims].append((i, c, keys[pos]))
+    with TRACER.span("measure.multi.keys").set(items=len(items)):
+        for i, (plan, _margs, key) in enumerate(items):
+            keys = np.asarray(jax.random.split(key, len(plan.cliques)))
+            for pos, c in enumerate(plan.cliques):
+                dims = tuple(plan.domain.attributes[a].size for a in c)
+                groups[dims].append((i, c, keys[pos]))
 
     specs, args, lane_keys, sig2s = [], [], [], []
     for dims, members in groups.items():
@@ -222,14 +224,22 @@ def measure_batch(items: Sequence[MultiItem], use_kernel: Optional[bool],
         stacks[w].append(keys_np)
     keys = tuple(np.concatenate(stacks[w]) for w in widths)
 
-    t0 = time.monotonic()
     with TRACER.span("measure.multi.launch").set(groups=len(specs)):
         for spec in specs:      # one kernel.chain marker per chain launched
             if spec.launch is not None:
                 with spec.launch.span(2 * spec.g_pad):
                     pass
-        outs = [np.asarray(o) for o in _flat_program(
-            tuple(specs), widths, dtype)(keys, tuple(args))]
+        flat_args = (keys, tuple(() if spec.nd else arg
+                                 for spec, arg in zip(specs, args)))
+        with TRACER.span("measure.multi.dispatch").set(stage="flat") as sp:
+            outs = _flat_program(tuple(specs), widths, dtype)(*flat_args)
+            if sp:
+                sp.set(bytes=host_bytes(flat_args))
+        with TRACER.span("measure.multi.fetch").set(stage="flat") as sp:
+            # Rebinding drops the device outputs before program 2 runs.
+            outs = [np.asarray(o) for o in outs]
+            if sp:
+                sp.set(bytes=sum(o.nbytes for o in outs))
         nd = [i for i, spec in enumerate(specs) if spec.nd]
         if nd:
             # The per-axis chains take N-D stacks: the reshape is free in
@@ -238,21 +248,29 @@ def measure_batch(items: Sequence[MultiItem], use_kernel: Optional[bool],
             for i in nd:
                 v, sig, operands = args[i]
                 shape = (specs[i].g_pad,) + specs[i].dims
-                nd_args.append((np.asarray(v).reshape(shape),
-                                outs[i].reshape(shape), sig, operands))
-            nd_outs = _nd_program(tuple(specs[i] for i in nd), dtype)(
-                tuple(nd_args))
-            for i, o in zip(nd, nd_outs):
-                outs[i] = np.asarray(o)
+                nd_args.append((v.reshape(shape), outs[i].reshape(shape),
+                                sig, operands))
+            nd_args = tuple(nd_args)
+            with TRACER.span("measure.multi.dispatch").set(stage="nd") as sp:
+                nd_outs = _nd_program(tuple(specs[i] for i in nd), dtype)(
+                    nd_args)
+                if sp:
+                    sp.set(bytes=host_bytes(nd_args))
+            with TRACER.span("measure.multi.fetch").set(stage="nd") as sp:
+                for i, o in zip(nd, nd_outs):
+                    outs[i] = np.asarray(o)
+                if sp:
+                    sp.set(bytes=sum(outs[i].nbytes for i in nd))
     for spec in specs:
         if spec.launch is not None:
-            spec.launch.record(2 * spec.g_pad, t0)
+            spec.launch.record(2 * spec.g_pad)
 
     out: List[Dict[Clique, Measurement]] = [dict() for _ in items]
-    for spec, members, om, s2 in zip(specs, groups.values(), outs, sig2s):
-        om = om.reshape(spec.g, -1)
-        for j, (i, c, _k) in enumerate(members):
-            out[i][c] = Measurement(c, om[j], s2[j])
+    with TRACER.span("measure.multi.unpack"):
+        for spec, members, om, s2 in zip(specs, groups.values(), outs, sig2s):
+            om = om.reshape(spec.g, -1)
+            for j, (i, c, _k) in enumerate(members):
+                out[i][c] = Measurement(c, om[j], s2[j])
     return out
 
 

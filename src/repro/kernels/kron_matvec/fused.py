@@ -42,7 +42,6 @@ Validated in interpret mode on CPU against the float64 numpy oracle
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import dataclass, field
 from functools import lru_cache
 from typing import List, Optional, Sequence, Tuple
@@ -54,23 +53,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.obs import REGISTRY, TRACER
+from repro.obs import TRACER
 from repro.obs.naming import chain_label
 
 from ._layout import interpret_default as _interpret_default
 from ._layout import normalize_factor as _normalize_factor
 from ._layout import pad_to as _pad_to
 from .stats import CHAIN_STATS
-
-# Measured dispatch time per chain launch, labeled like the roofline gauges
-# (obs/naming.py) so predicted-vs-measured is one /metrics join.  Host-side
-# dispatch timing: JAX execution is async, so this bounds launch overhead and
-# any synchronous work, not device busy time.
-_LAUNCH_SECONDS = REGISTRY.histogram(
-    "repro_kernel_launch_seconds",
-    "Host-side dispatch time of one kron-chain launch",
-    labels=("chain",),
-    buckets=(1e-5, 3e-5, 1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2, 0.1, 0.3, 1.0))
 
 _LANE = 128          # minor-axis (lane) padding quantum
 _SUB = 8             # sublane padding quantum (float32)
@@ -385,8 +374,8 @@ class ChainLaunch:
                 y = jnp.cumsum(y, axis=axis + 1)
         return y
 
-    def record(self, batch: int, t0: float) -> None:
-        """Count one launch of this chain (stats.py) and its dispatch time."""
+    def record(self, batch: int) -> None:
+        """Count one launch of this chain (stats.py)."""
         _LAUNCHED.add((self.plan.signature, batch, self.fused))
         n_epi = sum(1 for op in self.plan.epilogue if op)
         if self.fused:
@@ -398,8 +387,6 @@ class ChainLaunch:
                 CHAIN_STATS.inc(name, n_live)
             CHAIN_STATS.inc("fallback_chains")
         CHAIN_STATS.inc("epilogue_axes", n_epi)
-        label = chain_label(self.plan.in_dims, batch, self.plan.compute_dtype)
-        _LAUNCH_SECONDS.labels(chain=label).observe(time.monotonic() - t0)
 
     def span(self, batch: int):
         """The ``kernel.chain`` span of one launch of this chain."""
@@ -527,7 +514,6 @@ def fused_chain_matvec(factors: Sequence, x, dims: Sequence[int],
                            block_l=block_l, vmem_budget=vmem_budget,
                            epilogue=epilogue, compute_dtype=compute_dtype,
                            allow_narrow=allow_narrow)
-    t0 = time.monotonic()
     with launch.span(b):
         call = _build_launch_call(launch)
         if launch.fused:
@@ -536,5 +522,5 @@ def fused_chain_matvec(factors: Sequence, x, dims: Sequence[int],
             x_nd = np.asarray(x, np.float32).reshape((b,) + dims)
             y = jnp.asarray(np.asarray(call(launch.operands, x_nd))
                             .reshape(b, -1))
-    launch.record(b, t0)
+    launch.record(b)
     return y[0] if flat_in else y

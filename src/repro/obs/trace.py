@@ -16,6 +16,16 @@ Model (docs/OBSERVABILITY.md):
   clock read, no lock.  The no-op span is falsy so call sites can guard
   optional attribute work with ``if sp:``.  The serve-bench overhead gate
   (≤2% disabled) holds the fast path to that contract.
+* **On the profiler's clock**: while enabled, a span used as a context
+  manager also enters a ``jax.profiler.TraceAnnotation`` of its name, so a
+  profile's host plane carries the span beside the device's ops.  Spans
+  with a backdated ``t0`` or ended by :meth:`Span.end` from another scope
+  (``serve.request``, ``serve.queue_wait``, the shared ``serve.fuse``
+  markers, ``serve.idle``) stay JSONL-only: the profiler can only place
+  what it saw start.
+* **Collections**: while enabled, a ``gc.callbacks`` hook records every
+  collection as a ``python.gc`` span (``generation``, ``collected``) on the
+  thread that ran it, under that thread's current span.
 * **Sink**: finished spans land in a bounded ring (``deque(maxlen=...)``)
   and, when a path is configured (``REPRO_TRACE=/path`` or
   ``enable(path=...)``), are appended as one JSON object per line.  Writes
@@ -37,6 +47,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import json
 import os
 import threading
@@ -99,7 +110,8 @@ class Span:
     """
 
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id",
-                 "t0", "t1", "attrs", "_token", "_ended")
+                 "t0", "t1", "attrs", "_token", "_ended", "_backdated",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str,
                  trace_id: Optional[str] = None,
@@ -115,6 +127,8 @@ class Span:
         self.attrs: dict = {}
         self._token = None
         self._ended = False
+        self._backdated = t0 is not None
+        self._ann = None
 
     def __bool__(self) -> bool:
         return True
@@ -125,9 +139,16 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _CTX.set(self)
+        annotation = self.tracer._annotation
+        if annotation is not None and not self._backdated:
+            self._ann = annotation(self.name)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self._token is not None:
             _CTX.reset(self._token)
             self._token = None
@@ -168,7 +189,13 @@ class Tracer:
 
     def __init__(self, ring_size: int = 4096):
         self.enabled = False
-        self._lock = threading.Lock()
+        # jax.profiler.TraceAnnotation while enabled (imported then, so this
+        # module stays a stdlib-only leaf), else None.
+        self._annotation = None
+        self._gc_starts = threading.local()
+        # Reentrant: a collection can start inside _record on the same
+        # thread, and its python.gc span is recorded from the gc hook.
+        self._lock = threading.RLock()
         self._ring = deque(maxlen=ring_size)   # guarded-by: _lock
         self._fh = None                        # guarded-by: _lock
         self._path: Optional[str] = None       # guarded-by: _lock
@@ -178,7 +205,12 @@ class Tracer:
     # ------------------------------------------------------------ control
     def enable(self, path: Optional[str] = None,
                max_file_spans: int = MAX_FILE_SPANS) -> None:
-        """Turn tracing on, optionally appending spans to ``path`` (JSONL)."""
+        """Turn tracing on, optionally appending spans to ``path`` (JSONL).
+
+        Spans also go on the profiler's clock (``TraceAnnotation``), and
+        Python's collections are recorded as ``python.gc`` spans.
+        """
+        from jax.profiler import TraceAnnotation
         with self._lock:
             if self._fh is not None:
                 self._fh.close()
@@ -189,10 +221,16 @@ class Tracer:
             self._max_file_spans = max_file_spans
             if path:
                 self._fh = open(path, "a", encoding="utf-8")  # noqa: SIM115
+        self._annotation = TraceAnnotation
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
         self.enabled = True
 
     def disable(self) -> None:
         self.enabled = False
+        self._annotation = None
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
         with self._lock:
             if self._fh is not None:
                 self._fh.flush()
@@ -243,6 +281,27 @@ class Tracer:
 
     def current(self):
         return _CTX.get()
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: one ``python.gc`` span per collection, on
+        the collecting thread, under that thread's current span."""
+        starts = self._gc_starts
+        if phase == "start":
+            starts.t0 = time.monotonic()
+            annotation = self._annotation
+            starts.ann = annotation("python.gc") if annotation else None
+            if starts.ann is not None:
+                starts.ann.__enter__()
+            return
+        t0 = getattr(starts, "t0", None)
+        if t0 is None:                  # the hook came in mid-collection
+            return
+        if starts.ann is not None:
+            starts.ann.__exit__(None, None, None)
+        starts.t0 = starts.ann = None
+        self.span("python.gc", t0=t0).set(
+            generation=info["generation"],
+            collected=info["collected"]).end()
 
     # ------------------------------------------------------------- sink
     def _record(self, span: Span) -> None:

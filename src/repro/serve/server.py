@@ -289,6 +289,9 @@ class ReleaseServer:
 
     # -------------------------------------------------------------- worker
     def _worker_loop(self) -> None:
+        # Traced: when the worker last finished a batch.  Its wait from then
+        # to the next drain is one serve.idle span (backdated, so JSONL-only).
+        t_idle = None
         while not self._stop_evt.is_set():
             if not self._resume_evt.wait(timeout=0.05):
                 continue
@@ -311,6 +314,8 @@ class ReleaseServer:
                 except queue.Empty:
                     break
             self.stats.dequeue(len(batch))
+            if t_idle is not None:
+                TRACER.span("serve.idle", t0=t_idle).end()
             try:
                 self._serve_batch(batch)
             except Exception as exc:   # noqa: BLE001 — never kill the worker
@@ -323,6 +328,7 @@ class ReleaseServer:
             finally:
                 for _ in batch:
                     self._queue.task_done()
+                t_idle = time.monotonic() if TRACER.enabled else None
 
     def _key_for(self, p: _Pending) -> jax.Array:
         if p.request.seed is not None:
@@ -391,7 +397,8 @@ class ReleaseServer:
                         raise ValueError(
                             "kind='range' needs an RP+ plan; this tenant "
                             "registered a plain marginal plan")
-                    self._validate_marginals(sess, req)
+                    with TRACER.span("serve.validate", parent=p.trace):
+                        self._validate_marginals(sess, req)
                     p.charged = sess.pcost_per_release
                     with TRACER.span("serve.charge", parent=p.trace).set(
                             tenant=req.tenant, pcost=p.charged):

@@ -145,6 +145,129 @@ def test_tracer_off_returns_falsy_noop_singleton():
     sp.end()                                # idempotent no-op
 
 
+class _RaisingAnnotation:
+    def __init__(self, *a, **k):
+        raise AssertionError("TraceAnnotation constructed")
+
+
+def test_tracer_off_never_annotates_or_hooks_gc(monkeypatch):
+    """Disabled — never enabled, or enabled and disabled again — spans build
+    no TraceAnnotation and gc.callbacks is as it was."""
+    import gc
+
+    import jax.profiler
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _RaisingAnnotation)
+    before = list(gc.callbacks)
+    tr = Tracer()
+    for _ in range(2):
+        with tr.span("outer"):
+            with tr.span("inner"):
+                gc.collect()
+        assert gc.callbacks == before
+        tr.enable()
+        tr.disable()
+    assert tr.drain() == []
+
+
+def test_annotations_only_for_context_manager_spans(monkeypatch):
+    """Enabled, a ``with`` span enters and exits an annotation of its name,
+    nested as the spans nest; backdated and explicitly ended spans don't."""
+    import time
+
+    import jax.profiler
+    seen = []
+
+    class Recording:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recording)
+    tr = Tracer()
+    tr.enable()
+    try:
+        with tr.span("a"):
+            with tr.span("b"):
+                pass
+        tr.span("ended").end()
+        with tr.span("backdated", t0=time.monotonic() - 1.0):
+            pass
+    finally:
+        tr.disable()
+    assert [e for e in seen if e[1] != "python.gc"] == [
+        ("enter", "a"), ("enter", "b"), ("exit", "b"), ("exit", "a")]
+    assert {s.name for s in tr.drain()} >= {"a", "b", "ended", "backdated"}
+
+
+def test_gc_hook_records_python_gc_spans():
+    """Enabled, a collection yields a python.gc span under the collecting
+    thread's current span; disable() removes the hook."""
+    import gc
+    tr = Tracer()
+    tr.enable()
+    try:
+        assert tr._on_gc in gc.callbacks
+        with tr.span("outer") as outer:
+            gc.collect()
+    finally:
+        tr.disable()
+    assert tr._on_gc not in gc.callbacks
+    spans = tr.drain()
+    gcs = [s for s in spans if s.name == "python.gc"
+           and s.attrs["generation"] == 2]
+    assert gcs and all(s.parent_id == outer.span_id for s in gcs)
+    assert all(isinstance(s.attrs["collected"], int) for s in gcs)
+    assert all(outer.t0 <= s.t0 <= s.t1 <= outer.t1 for s in gcs)
+    gc.collect()
+    assert not [s for s in tr.drain() if s.name == "python.gc"]
+
+
+def test_spans_land_on_the_profiler_clock(tmp_path):
+    """A CPU profile of nested spans carries each one on a host plane; its
+    JSONL t0, put on the profile's clock through one marker, lands within
+    0.5 ms of its annotation."""
+    import glob
+    import time
+
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+    names = ("obs.clock.outer", "obs.clock.mid", "obs.clock.leaf")
+    tr = Tracer()
+    tr.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("obs.clock.sync"):
+            sync = time.monotonic()
+        with tr.span(names[0]):
+            time.sleep(0.002)
+            with tr.span(names[1]):
+                time.sleep(0.002)
+                with tr.span(names[2]):
+                    time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+        tr.disable()
+    spans = {s.name: s for s in tr.drain() if s.name in names}
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(e)
+    (marker,) = events["obs.clock.sync"]
+    for name in names:
+        (ev,) = events[name]
+        want_ns = marker.start_ns + (spans[name].t0 - sync) * 1e9
+        assert abs(want_ns - ev.start_ns) < 0.5e6, name
+        assert ev.duration_ns <= (spans[name].t1 - spans[name].t0) * 1e9
+
+
 def test_span_tree_parents_via_context():
     tr = Tracer()
     tr.enable()                             # ring only, no file

@@ -461,6 +461,166 @@ def test_trace_id_propagates_serve_to_kernel(tmp_path):
                           for s in kernel_spans)
 
 
+@pytest.mark.parametrize("fused", [True, False])
+def test_served_release_span_tree(tmp_path, fused):
+    """A fused batch and a solo request each yield the validation,
+    measurement, transfer and reconstruction spans under the right parents:
+    kernel.chain stays a direct child of measure.multi.launch, and
+    measure.multi.group keeps the launch's parent."""
+    from repro.obs import TRACER
+    n = 2 if fused else 1
+    plans, margs = _tenant_setup(n)
+    TRACER.enable()
+    TRACER.drain()
+    try:
+        srv = _server(tmp_path, plans, max_batch=8, use_kernel=True)
+        try:
+            srv.pause()
+            futs = [srv.submit(ReleaseRequest(tenant=f"t{i}",
+                                              marginals=margs[i], seed=i))
+                    for i in range(n)]
+            srv.resume()
+            assert [f.result(300).batched for f in futs] == [fused] * n
+        finally:
+            srv.stop()
+            srv.ledger.close()
+        spans = TRACER.drain()
+    finally:
+        TRACER.disable()
+    by_id = {s.span_id: s for s in spans}
+
+    def parent(s):
+        return by_id[s.parent_id].name
+
+    def named(name):
+        return [s for s in spans if s.name == name]
+
+    (launch,) = named("measure.multi.launch")
+    assert parent(launch) == ("serve.fuse" if fused else "engine.measure")
+    for name in ("measure.multi.keys", "measure.multi.unpack"):
+        (sp,) = named(name)
+        assert sp.parent_id == launch.parent_id
+    groups = named("measure.multi.group")
+    assert len(groups) == 3                     # (), (5,), (5, 5)
+    assert all(g.parent_id == launch.parent_id for g in groups)
+    chains = named("kernel.chain")
+    assert len(chains) == 2 and all(k.parent_id == launch.span_id
+                                    for k in chains)
+    for name in ("measure.multi.dispatch", "measure.multi.fetch"):
+        (sp,) = named(name)
+        assert sp.parent_id == launch.span_id and sp.attrs["stage"] == "flat"
+        assert sp.attrs["bytes"] > 0
+    validates = named("serve.validate")
+    assert len(validates) == n
+    assert all(parent(v) == "serve.request" for v in validates)
+    recs = named("engine.reconstruct")
+    assert len(recs) == n
+    for rec in recs:
+        kids = [s for s in spans if s.parent_id == rec.span_id]
+        assert sorted(k.name for k in kids) == [
+            "reconstruct.dispatch", "reconstruct.embed", "reconstruct.embed",
+            "reconstruct.fetch", "reconstruct.prepare",
+            "reconstruct.prepare"]
+        assert all(k.attrs["bytes"] > 0 for k in kids
+                   if k.name in ("reconstruct.dispatch", "reconstruct.fetch"))
+
+
+def test_worker_idle_span_between_batches(tmp_path):
+    """Traced, the worker's wait from the end of one batch to the next drain
+    is one serve.idle span; untraced, it records nothing."""
+    from repro.obs import TRACER
+    plans, margs = _tenant_setup(1)
+    srv = _server(tmp_path, plans, max_batch=1)
+    try:
+        TRACER.enable()
+        TRACER.drain()
+        try:
+            for seed in range(3):
+                srv.request_sync(ReleaseRequest(tenant="t0",
+                                                marginals=margs[0], seed=seed))
+            spans = TRACER.drain()
+        finally:
+            TRACER.disable()
+        srv.request_sync(ReleaseRequest(tenant="t0", marginals=margs[0],
+                                        seed=3))
+    finally:
+        srv.stop()
+        srv.ledger.close()
+    idles = sorted((s for s in spans if s.name == "serve.idle"),
+                   key=lambda s: s.t0)
+    drains = sorted(s.t1 for s in spans if s.name == "serve.queue_wait")
+    assert len(idles) == 2 and len(drains) == 3
+    for idle, drain in zip(idles, drains[1:]):
+        assert idle.parent_id is None and idle.t0 < idle.t1 <= drain
+    assert not TRACER.drain()
+
+
+def _expected_bytes(items, nd_dims):
+    """Bytes each transfer span should count, from the group shapes alone
+    (float32 lanes and σ, uint32[2] keys)."""
+    from repro.engine.multi import lane_bucket
+    lanes = {}
+    for plan, _m, _k in items:
+        for c in plan.cliques:
+            dims = tuple(plan.domain.attributes[a].size for a in c)
+            lanes[dims] = lanes.get(dims, 0) + 1
+    want = {"dispatch.flat": 0, "fetch.flat": 0, "dispatch.nd": 0,
+            "fetch.nd": 0}
+    for dims, g in lanes.items():
+        g_pad, m = lane_bucket(g), int(np.prod(dims))
+        out = int(np.prod([n - 1 for n in dims]))
+        want["dispatch.flat"] += 8 * g_pad              # keys
+        if dims in nd_dims:
+            want["fetch.flat"] += 4 * g_pad * m         # noise rows out ...
+            want["dispatch.nd"] += 4 * (2 * g_pad * m + g)  # ... and back
+            want["fetch.nd"] += 4 * g * out
+        else:
+            want["dispatch.flat"] += 4 * (g_pad * m + g)
+            want["fetch.flat"] += 4 * g * out
+    return want
+
+
+@pytest.mark.parametrize("n_items", [1, 3])
+def test_transfer_byte_counters_exact(monkeypatch, n_items):
+    """Every ``bytes`` attribute equals the sum over its groups' shapes,
+    for a solo and a fused batch, with one signature on the per-axis chain
+    (so its noise rows make the round trip) and reconstruction's input and
+    tables."""
+    from repro.core.reconstruct import reconstruct_all_batched
+    from repro.obs import TRACER
+    nd_dims = {(5, 5)}
+    prepare = multi_mod.prepare_chain
+
+    def per_axis_for_nd(factors, dims, batch, **kw):
+        if tuple(dims) in nd_dims:
+            kw["vmem_budget"] = 16              # over budget: per-axis chain
+        return prepare(factors, dims, batch, **kw)
+
+    monkeypatch.setattr(multi_mod, "prepare_chain", per_axis_for_nd)
+    plans, margs = _tenant_setup(n_items)
+    items = [(p, m, jax.random.PRNGKey(i))
+             for i, (p, m) in enumerate(zip(plans, margs))]
+    TRACER.enable()
+    TRACER.drain()
+    try:
+        measured = measure_multi(items, use_kernel=True)
+        reconstruct_all_batched(plans[0], measured[0], use_kernel=True)
+        spans = TRACER.drain()
+    finally:
+        TRACER.disable()
+    got = {}
+    for s in spans:
+        if s.name.startswith("measure.multi.") and "stage" in s.attrs:
+            got[f"{s.name.rsplit('.', 1)[1]}.{s.attrs['stage']}"] = \
+                s.attrs["bytes"]
+    assert got == _expected_bytes(items, nd_dims)
+    cells = sum(int(np.prod([DOM.attributes[a].size for a in c]))
+                for c in plans[0].workload.cliques if c)
+    (disp,) = [s for s in spans if s.name == "reconstruct.dispatch"]
+    (fetch,) = [s for s in spans if s.name == "reconstruct.fetch"]
+    assert disp.attrs["bytes"] == fetch.attrs["bytes"] == 4 * cells
+
+
 def test_metrics_endpoint_parseable_under_concurrent_traffic(tmp_path):
     """16 threads of mixed traffic + /metrics scrapes: every scrape parses,
     and the final exposition agrees with /stats (one backing store)."""

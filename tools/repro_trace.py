@@ -8,14 +8,15 @@ src/repro/obs/trace.py for the schema).  Usage:
     python tools/repro_trace.py trace.jsonl              # waterfall per trace
     python tools/repro_trace.py trace.jsonl --list       # one line per trace
     python tools/repro_trace.py trace.jsonl --trace-id 8f3c0a...
-    python tools/repro_trace.py trace.jsonl --kernels    # per-chain timing
+    python tools/repro_trace.py trace.jsonl --kernels    # launches per chain
     python tools/repro_trace.py trace.jsonl --json       # machine-readable
 
 The waterfall shows, for every request trace, the span tree (indent =
 parent link) with a time bar scaled to the trace's wall clock, plus a
 critical-path breakdown: how much of the root span went to queue wait,
-ledger charge, fused measurement (with kernel time called out separately),
-release postprocessing, and synthesis.  See docs/OBSERVABILITY.md.
+ledger charge, fused measurement, release postprocessing, and synthesis,
+with the time spent waiting on the device and copying back called out
+separately.  See docs/OBSERVABILITY.md.
 """
 from __future__ import annotations
 
@@ -28,9 +29,10 @@ from typing import Dict, List, Optional
 BAR_WIDTH = 40
 
 # Span names that make up the serve critical path, in pipeline order.
-# "kernel" is reported as a sub-bucket of "measure" (kernel.chain spans are
-# children of serve.fuse / engine.measure, so their time is already inside
-# the measure bucket — double counting it in the sum would overshoot 100%).
+# "device_wait" (every *.fetch span: the wait on a device program and the
+# copy of its outputs to the host) is reported as a sub-bucket: the fetch
+# spans are children of the measure and release spans, so their time is
+# already inside those buckets — counting it in the sum would overshoot.
 PHASES = (
     ("queue_wait", ("serve.queue_wait",)),
     ("charge", ("serve.charge",)),
@@ -133,10 +135,10 @@ def render_waterfall(trace_id: str, spans: List[dict], out=sys.stdout) -> None:
 def critical_path(spans: List[dict]) -> Dict[str, float]:
     """Phase breakdown of one trace in microseconds.
 
-    Each bucket sums the spans listed in :data:`PHASES`; ``kernel`` reports
-    the kernel.chain time nested inside the measure bucket; ``other`` is the
-    root duration not covered by any top-level bucket (scheduling, python
-    glue).  Buckets with zero time are omitted.
+    Each bucket sums the spans listed in :data:`PHASES`; ``device_wait``
+    reports the ``*.fetch`` time nested inside the measure and release
+    buckets; ``other`` is the root duration not covered by any top-level
+    bucket (scheduling, python glue).  Buckets with zero time are omitted.
     """
     root = find_root(spans)
     by_phase: Dict[str, float] = {}
@@ -144,11 +146,11 @@ def critical_path(spans: List[dict]) -> Dict[str, float]:
         t = sum(s["dur_us"] for s in spans if s["name"] in names)
         if t > 0:
             by_phase[phase] = t
-    kern = sum(s["dur_us"] for s in spans if s["name"] == "kernel.chain")
-    if kern > 0:
-        by_phase["kernel"] = kern
+    wait = sum(s["dur_us"] for s in spans if s["name"].endswith(".fetch"))
+    if wait > 0:
+        by_phase["device_wait"] = wait
     if root is not None:
-        covered = sum(v for k, v in by_phase.items() if k != "kernel")
+        covered = sum(v for k, v in by_phase.items() if k != "device_wait")
         other = root["dur_us"] - covered
         if other > 0.05 * root["dur_us"]:
             by_phase["other"] = other
@@ -157,7 +159,9 @@ def critical_path(spans: List[dict]) -> Dict[str, float]:
 
 
 def kernel_table(spans: List[dict], out=sys.stdout) -> List[dict]:
-    """Per-chain kernel launch timing, aggregated over every trace."""
+    """Chain launches and the rows they launched, per chain label, over
+    every trace.  ``kernel.chain`` spans are zero-length markers: all chains
+    of a batch run in one program, so they carry counts, not durations."""
     groups: Dict[tuple, List[dict]] = defaultdict(list)
     for s in spans:
         if s["name"] != "kernel.chain":
@@ -167,22 +171,20 @@ def kernel_table(spans: List[dict], out=sys.stdout) -> List[dict]:
                 bool(attrs.get("fused", False)))].append(s)
     rows = []
     for (chain, fused), ss in sorted(groups.items()):
-        durs = sorted(s["dur_us"] for s in ss)
+        per_launch = chain.split("/")[1][1:] if chain.count("/") == 2 else ""
         rows.append({
             "chain": chain, "fused": fused, "launches": len(ss),
-            "total_ms": sum(durs) / 1000.0,
-            "mean_us": sum(durs) / len(durs),
-            "min_us": durs[0], "max_us": durs[-1],
+            "rows": len(ss) * int(per_launch) if per_launch.isdigit()
+            else None,
             "tune_source": (ss[0].get("attrs") or {}).get("tune_source"),
         })
     if out is not None:
-        out.write(f"{'chain':<20} {'fused':>5} {'n':>5} {'total':>10} "
-                  f"{'mean':>10} {'min':>10} {'max':>10}  tune\n")
+        out.write(f"{'chain':<20} {'fused':>5} {'launches':>8} "
+                  f"{'rows':>10}  tune\n")
         for r in rows:
             out.write(f"{r['chain']:<20} {str(r['fused']):>5} "
-                      f"{r['launches']:>5} {r['total_ms']:>9.3f}m "
-                      f"{r['mean_us']:>9.1f}u {r['min_us']:>9.1f}u "
-                      f"{r['max_us']:>9.1f}u  {r['tune_source']}\n")
+                      f"{r['launches']:>8} {str(r['rows']):>10}  "
+                      f"{r['tune_source']}\n")
     return rows
 
 
@@ -217,7 +219,7 @@ def main(argv=None) -> int:
     ap.add_argument("--list", action="store_true",
                     help="one summary line per trace, no waterfalls")
     ap.add_argument("--kernels", action="store_true",
-                    help="per-chain kernel timing table only")
+                    help="chain launches and rows per chain label only")
     ap.add_argument("--json", action="store_true", dest="as_json",
                     help="emit the report as JSON instead of text")
     args = ap.parse_args(argv)
