@@ -69,7 +69,7 @@ def subset_slot_region(clique: Clique, sub_clique: Clique,
     ``0..slot_dims[i]−2`` when i ∈ A', the marginalized part the single last
     slot otherwise.  Distinct subsets occupy disjoint regions — the identity
     behind merged reconstruction (§5), shared by the plain path
-    (slot_dims = n_i), the RP+ path (slot_dims = r_i+1,
+    (slot_dims = n_i, :func:`embed_group`), the RP+ path (slot_dims = r_i+1,
     ``core/plus.py``) and the batched engine embedding
     (``engine/plus_engine.py``): one definition, three consumers.
     """
@@ -80,15 +80,35 @@ def subset_slot_region(clique: Clique, sub_clique: Clique,
     return region, shape
 
 
+def embed_group(measurements: Mapping[Clique, Measurement],
+                group: Sequence[Clique], sizes: Sequence[int],
+                dtype=np.float64) -> np.ndarray:
+    """Σ_{A'⊆A} e_{A'} for every clique A of one signature group, one row each.
+
+    Returns the ``(len(group), Π sizes)`` input of the group's merged U-chain.
+    A subset's slot region depends only on which positions of A it keeps, so
+    each of the 2^k position patterns fills its region for all rows with one
+    block copy: the rows' ω concatenated, cast to ``dtype`` in that single
+    assignment.  The regions tile the tensor, so every cell is written.
+    """
+    positions, rows = tuple(range(len(sizes))), len(group)
+    out = np.empty((rows, math.prod(sizes)), dtype=dtype)
+    t = out.reshape((rows,) + tuple(sizes))
+    cols = tuple(zip(*group))             # cols[j]: every row's j-th attribute
+    for pos in subsets(positions):
+        region, shape = subset_slot_region(positions, pos, sizes)
+        keys = zip(*(cols[j] for j in pos)) if pos else [()] * rows
+        t[(slice(None),) + region] = np.concatenate(
+            [measurements[key].omega for key in keys],
+            axis=None).reshape((rows,) + shape)
+    return out
+
+
 def embed_subset_answers(plan: Plan, measurements: Mapping[Clique, Measurement],
                          clique: Clique, dtype=np.float64) -> np.ndarray:
     """Sum of subset embeddings Σ_{A'⊆A} e_{A'} — input of the merged U-chain."""
     sizes = plan.domain.clique_sizes(clique)
-    t = np.zeros(sizes, dtype=dtype)
-    for sub in subsets(clique):
-        region, shape = subset_slot_region(clique, sub, sizes)
-        t[region] = np.asarray(measurements[sub].omega, dtype=dtype).reshape(shape)
-    return t
+    return embed_group(measurements, [clique], sizes, dtype).reshape(sizes)
 
 
 def reconstruct_marginal(plan: Plan, measurements: Mapping[Clique, Measurement],
@@ -182,14 +202,13 @@ def reconstruct_all_batched(plan: Plan, measurements: Mapping[Clique, Measuremen
                 operands = launch.operands
             else:
                 operands = tuple(jnp.asarray(f) for f in factors)
-        with TRACER.span("reconstruct.embed").set(dims=dims,
-                                                  rows=len(group)):
-            x = np.stack([embed_subset_answers(plan, measurements, c)
-                          .reshape(-1) for c in group])
-            if launch is not None:
-                x = x.astype(np.float32)
-                if not launch.fused:      # the per-axis chain takes it N-D
-                    x = x.reshape((len(group),) + tuple(sizes))
+        with TRACER.span("reconstruct.embed").set(
+                dims=dims, rows=len(group), copies=2 ** len(sizes)):
+            x = embed_group(measurements, group, sizes,
+                            np.float64 if launch is None else np.float32)
+            if launch is not None and not launch.fused:
+                # the per-axis chain takes it N-D
+                x = x.reshape((len(group),) + tuple(sizes))
         specs.append((tuple(sizes), launch))
         args.append((x, operands))
         groups.append(group)

@@ -523,6 +523,11 @@ def test_served_release_span_tree(tmp_path, fused):
             "reconstruct.prepare"]
         assert all(k.attrs["bytes"] > 0 for k in kids
                    if k.name in ("reconstruct.dispatch", "reconstruct.fetch"))
+        # one block copy per subset position, over the whole group
+        embeds = {k.attrs["dims"]: k for k in kids
+                  if k.name == "reconstruct.embed"}
+        assert {d: (e.attrs["rows"], e.attrs["copies"])
+                for d, e in embeds.items()} == {"5": (3, 2), "5x5": (3, 4)}
 
 
 def test_worker_idle_span_between_batches(tmp_path):
