@@ -1,10 +1,13 @@
 """One run of one cell: set-up, a measured window, the check, one result line.
 
-The cell, its configuration and its traffic mix are found by name:
-``BENCHMARK.json`` names the cell's configuration and mix, the configuration
-is ``bench/configs/<config>.json`` and the mix ``bench/mixes/<traffic>.json``,
-and each per-layer metric is read by ``bench/metrics/<metric>.py``.  A new
-cell or metric is new files and entries; this file does not change.
+A cell is found by name: its entry in ``BENCHMARK.json`` names a
+configuration and a traffic mix; the configuration is the file the entry of
+``configs`` gives (``bench/configs/<config>.json``), and its
+``deployment_kind`` names ``bench/kinds/<kind>.py``, which builds the plan,
+the tenants, each request and the check (the protocol is in
+``bench/kinds/__init__.py``); the mix is ``bench/mixes/<traffic>.json``, and
+each per-layer metric is read by ``bench/metrics/<metric>.py``.  A new cell,
+kind or metric is new files and entries; this file does not change.
 
 The window drives ``ReleaseServer.submit`` -> the resolved future in this
 process, with the durable ledger, from closed-loop client threads; latency is
@@ -31,7 +34,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from bench import traffic
-from bench.check import Reference, judge, ledger_numbers
+from bench.check import judge
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = Path(__file__).resolve().parent
@@ -39,7 +42,8 @@ RESULT_TIMEOUT_S = 600.0
 
 
 class CellError(Exception):
-    """The cell cannot run here: unknown name, missing files or no chip."""
+    """The cell cannot run here: unknown name or kind, missing files or no
+    chip."""
 
 
 @dataclass
@@ -48,6 +52,7 @@ class Cell:
     chips: int
     config: dict
     mix: dict
+    kind: type          # bench/kinds/<deployment_kind>.py's Deployment
     end_to_end: List[dict]
     per_layer: List[dict]
 
@@ -67,8 +72,25 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     config = json.loads((root / configs[w["config"]]["file"]).read_text())
     mix = json.loads((BENCH / "mixes" / f"{w['traffic']}.json").read_text())
     return Cell(name, int(w["chips"]), config, mix,
+                deployment_kind(config, w["config"], root),
                 [m for m in spec["end_to_end"] if _applies(m, name)],
                 [m for m in spec["per_layer"] if _applies(m, name)])
+
+
+def deployment_kind(config: dict, config_name: str, root: Path = ROOT) -> type:
+    """``bench/kinds/<deployment_kind>.py``'s ``Deployment``; no default."""
+    kind = config.get("deployment_kind")
+    if not isinstance(kind, str) or not kind.isidentifier():
+        raise CellError(f"configuration {config_name!r} names no "
+                        f"deployment_kind (got {kind!r})")
+    path = root / "bench" / "kinds" / f"{kind}.py"
+    if not path.is_file():
+        raise CellError(f"configuration {config_name!r}: no deployment kind "
+                        f"{kind!r} ({path} is missing)")
+    spec = importlib.util.spec_from_file_location(f"bench_kind_{kind}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.Deployment
 
 
 def metric_reader(name: str):
@@ -110,9 +132,8 @@ class Loop:
     """Closed-loop clients: each keeps one request outstanding."""
 
     srv: object
-    streams: list
+    deployment: object
     names: List[str]
-    layout: object
     seconds: float
     sample_k: int
     rng: object
@@ -131,14 +152,10 @@ class Loop:
     n_clients: int = 0
 
     def client(self, t: int) -> None:
-        from repro.serve import ReleaseRequest
-        stream, name = self.streams[t], self.names[t]
+        name = self.names[t]
         first = True
         while not self.stop.is_set():
-            r, buf = stream.next_request()
-            seed = stream.noise_seed(r)
-            req = ReleaseRequest(tenant=name, marginals=self.layout.views(buf),
-                                 seed=seed)
+            r, req, item = self.deployment.next_request(t)
             t0 = time.monotonic()
             fut = self.srv.submit(req)
             with self.lock:
@@ -154,9 +171,9 @@ class Loop:
                 with self.lock:
                     self.failures.append(f"{name} release {r}: {exc!r}")
                 continue
-            self._complete(t, r, seed, buf, res, t0, time.monotonic())
+            self._complete(t, r, item, res, t0, time.monotonic())
 
-    def _complete(self, t, r, seed, buf, res, t0, t1) -> None:
+    def _complete(self, t, r, item, res, t0, t1) -> None:
         with self.lock:
             self.done.append((t0, t1, t, r))
             if self.t_open is None:
@@ -167,13 +184,13 @@ class Loop:
                 return
             # Reservoir sample, drawn from the seed, of the window's releases.
             self.n_in_window += 1
-            item = (t, r, seed, buf, res)
+            kept = (t, r, item, res)
             if len(self.sample) < self.sample_k:
-                self.sample.append(item)
+                self.sample.append(kept)
             else:
                 j = int(self.rng.integers(0, self.n_in_window))
                 if j < self.sample_k:
-                    self.sample[j] = item
+                    self.sample[j] = kept
 
     def completions_since(self, t: float) -> int:
         with self.lock:
@@ -233,47 +250,34 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         raise CellError(f"cell {cell.name} needs {cell.chips} TPU chip(s); "
                         f"JAX sees {len(devices)} {devices[0].platform} "
                         f"device(s)")
-    from repro.core import Domain, all_kway, select
     from repro.obs import TRACER
     from repro.runtime import enable_compile_cache
-    from repro.serve import BudgetLedger, ReleaseRequest, ReleaseServer
+    from repro.serve import BudgetLedger, ReleaseServer
 
     enable_compile_cache()
     clock = CompileClock()
     cfg, mix = cell.config, cell.mix
     own_dir = workdir is None
     workdir = workdir or tempfile.mkdtemp(prefix="bench-")
-    sizes = list(cfg["sizes"])
 
-    # ---- set-up: plan, tables, server, tenants, one warm batch -----------
-    dom = Domain.create(sizes)
-    wk = all_kway(dom, int(cfg["kmax"]), include_lower=True).reweighted(
-        cfg["weights"])
-    plan = select(wk, pcost_budget=float(cfg["pcost_per_release"]))
-    workload = traffic.workload_cliques(len(sizes), int(cfg["kmax"]))
-    layout = traffic.TableLayout(sizes, traffic.closure_order(workload))
-    n_tenants = int(mix["tenants"])
-    streams = traffic.tenant_streams(layout, seed, n_tenants,
-                                     int(cfg["records_per_tenant"]),
-                                     int(cfg["records_per_release"]),
-                                     float(cfg["zipf_skew"]))
-    names = [f"tenant-{t}" for t in range(n_tenants)]
+    # ---- set-up: the kind's inputs, server, tenants, one warm batch -------
+    dep = cell.kind(cell, seed, devices[:cell.chips])
+    names = list(dep.tenants)
+    n_tenants = len(names)
     journal = os.path.join(workdir, "ledger.jsonl")
     ledger = BudgetLedger(journal, fsync=bool(cfg["ledger_fsync"]))
     srv = ReleaseServer(ledger, max_batch=int(mix["max_batch"]),
-                        max_wait_ms=float(mix["max_wait_ms"])).start()
-    for name in names:
-        srv.register_tenant(name, plan, pcost=float(cfg["pcost_total"]))
-    loop = Loop(srv, streams, names, layout, float(seconds),
-                int(mix["check_sample"]), traffic.sample_rng(seed))
+                        max_wait_ms=float(mix["max_wait_ms"]),
+                        **dep.server_kwargs).start()
+    dep.register(srv)
+    loop = Loop(srv, dep, names, float(seconds), int(mix["check_sample"]),
+                traffic.sample_rng(seed))
     srv.pause()
     warm = []
     for j in range(int(mix["max_batch"])):
         t = j % n_tenants
-        r, buf = streams[t].next_request()
-        warm.append(srv.submit(ReleaseRequest(
-            tenant=names[t], marginals=layout.views(buf),
-            seed=streams[t].noise_seed(r))))
+        _r, req, _item = dep.next_request(t)
+        warm.append(srv.submit(req))
         loop.submitted[names[t]] += 1
     srv.resume()
     for f in warm:
@@ -337,9 +341,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             cell=cell, spans=_read_spans(spans_path), t_open=t_open,
             t_close=t_close, compiles_in_window=sum(1 for t in clock.log
                                    if t_open <= t <= t_close),
-            plan_groups=layers.reconstruct_groups(sizes, workload),
-            chain_fused=layers.chain_paths(srv, plan),
-            profile=prof, device_kind=devices[0].device_kind)
+            profile=prof, device_kind=devices[0].device_kind,
+            **dep.layer_inputs(srv))
         for m in cell.per_layer:
             value = metric_reader(m["name"])(ctx)
             if value is not None:
@@ -355,23 +358,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                                       "unit": m["unit"]}
 
     # ---- the check: after the window, with the program's state dropped ------
-    sample = sorted(loop.sample, key=lambda s: (s[0], s[1]))
-    del srv, loop.srv, streams, loop.streams
+    sample = [(item, res) for _t, _r, item, res in
+              sorted(loop.sample, key=lambda s: (s[0], s[1]))]
+    del srv, loop.srv
+    dep.drop()
     gc.collect()
-    ref = Reference(sizes, workload, layout.cliques,
-                    float(cfg["pcost_per_release"]))
-    numbers = {"meas_ulp": 0.0, "table_sd": 0.0, "sigma2_rel": 0.0}
-    for t, r, nseed, buf, res in sample:
-        margs = layout.views(buf.astype(np.float64))
-        got = ref.compare(ref.release(margs, nseed),
-                          {c: m.omega for c, m in res.measurements.items()},
-                          {c: m.sigma2 for c, m in res.measurements.items()},
-                          res.tables)
-        for k, v in got.items():
-            numbers[k] = max(numbers[k], v)
-    if not sample:
-        numbers = {k: math.inf for k in numbers}
-    numbers.update(ledger_numbers(journal, loop.submitted, ref.pcost))
+    numbers = dep.check(sample, journal, loop.submitted)
     numbers["failed"] = float(len(loop.failures))
     ok, rows = judge(numbers, cfg["correct_limits"])
     if own_dir:
