@@ -44,11 +44,12 @@ class PrivacyRegistry:
     # Calls whose RESULT is raw (pre-noise) data.
     source_calls: FrozenSet[str] = frozenset({
         "exact_marginals_from_x", "sharded_marginals", "_local_marginal",
-        "marginals_from_records", "synthetic_records",
+        "_local_marginals", "marginals_from_records", "synthetic_records",
     })
     # Attribute reads that yield raw data wherever they appear
-    # (request payloads: ``req.marginals``).
-    source_attrs: FrozenSet[str] = frozenset({"marginals"})
+    # (request payloads: ``req.marginals``, ``req.records``; a tenant's
+    # resident ``RecordStore``, read as ``sess.records``).
+    source_attrs: FrozenSet[str] = frozenset({"marginals", "records"})
     # Parameters of these names are raw on entry (data-plane helpers).
     source_params: FrozenSet[str] = frozenset({"records", "marginals"})
     # Calls whose result is differentially private — taint stops here.

@@ -1,10 +1,16 @@
 """Mesh-sharded measurement engine (beyond-paper scale-out of Algorithm 1).
 
 Records are sharded over the ('pod','data') axes; every device builds partial
-marginal tables for the plan's closure via a one-hot matmul (MXU-friendly —
-no scatters), partial tables are psum'd, and the residual transform + noise
-run replicated (noise keys are identical across devices, so each device holds
-the same noisy answers — measurement is read-only on the records).
+marginal tables for the plan's closure as one-hot histograms (a one-hot of
+each record's cell code, summed over the records: a reduction, no scatter),
+partial tables are psum'd, and the residual transform + noise run replicated
+(noise keys are identical across devices, so each device holds the same
+noisy answers — measurement is read-only on the records).
+
+:class:`RecordStore` holds one tenant's records on one device for the
+release server: int32 rows in a buffer with headroom, counted by the same
+histogram over the rows that hold records, so a store that grows between
+releases compiles nothing new.
 
 The paper notes base mechanisms "can be run in parallel" (§5.2); this module
 is that observation turned into a pjit/shard_map program.  The replicated
@@ -19,6 +25,7 @@ from __future__ import annotations
 import os
 import weakref
 from collections import OrderedDict
+from functools import lru_cache, partial
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
@@ -245,29 +252,40 @@ def _local_marginal(records, cols, strides, n_cells, dtype=None):
     return _local_marginals(records, [(cols, strides, n_cells)], dtype)[0]
 
 
-def _local_marginals(records, metas, dtype=None):
+def _chunk_rows(metas) -> int:
+    """Rows one histogram step takes: the widest clique's one-hot block
+    stays within ``_ONE_HOT_ELEMS`` elements."""
+    return max(1, _ONE_HOT_ELEMS // max(m[2] for m in metas))
+
+
+def _local_marginals(records, metas, dtype=None, n_rows=None):
     """One-hot histograms of several cliques, ``metas[i] = (cols, strides,
     n_cells)``, over the same records.
 
-    One scan consumes the records in chunks of ``_ONE_HOT_ELEMS //
+    One loop consumes the records in chunks of ``_ONE_HOT_ELEMS //
     max(n_cells)`` rows and builds every clique's table in its body, so no
     ``(rows, n_cells)`` one-hot grows with N and the program holds one loop
     whatever the clique count (a loop per clique, each with its own chunk
     shape, made the TPU compile grow with N).  Rows past N in the last chunk
     map to index -1, whose one-hot row is all zeros.
+
+    ``n_rows`` (a traced scalar) counts only the first ``n_rows`` rows of a
+    buffer that is one chunk or a whole number of them (:class:`RecordStore`):
+    the loop runs over the chunks that hold those rows, so one compiled
+    program serves every row count the buffer holds.  ``None``: all of
+    ``records``.
     """
     dtype = noise_dtype() if dtype is None else dtype
-    n = records.shape[0]
+    n = records.shape[0] if n_rows is None else n_rows
     live = [m for m in metas if len(m[0])]
     if not live:
-        return tuple(jnp.asarray([n], dtype) for _ in metas)
-    chunk = max(1, min(n, _ONE_HOT_ELEMS // max(m[2] for m in live)))
-    n_chunks = -(-n // chunk)
-    recs = jnp.pad(records, ((0, n_chunks * chunk - n), (0, 0)))
-    recs = recs.reshape(n_chunks, chunk, records.shape[1])
+        return tuple(jnp.reshape(n, (1,)).astype(dtype) for _ in metas)
+    chunk = max(1, min(records.shape[0], _chunk_rows(live)))
+    if records.shape[0] % chunk:
+        records = jnp.pad(records, ((0, -records.shape[0] % chunk), (0, 0)))
 
-    def step(acc, xs):
-        i, rec = xs
+    def step(i, acc):
+        rec = jax.lax.dynamic_slice_in_dim(records, i * chunk, chunk)
         valid = i * chunk + jnp.arange(chunk) < n
         out = []
         for h, (cols, strides, n_cells) in zip(acc, live):
@@ -277,14 +295,13 @@ def _local_marginals(records, metas, dtype=None):
             flat = jnp.where(valid, flat, -1)
             out.append(h + jnp.sum(jax.nn.one_hot(flat, n_cells, dtype=dtype),
                                    axis=0))
-        return tuple(out), None
+        return tuple(out)
 
-    hists, _ = jax.lax.scan(
-        step, tuple(jnp.zeros((m[2],), dtype) for m in live),
-        (jnp.arange(n_chunks), recs))
-    hists = iter(hists)
-    return tuple(next(hists) if len(m[0]) else jnp.asarray([n], dtype)
-                 for m in metas)
+    hists = iter(jax.lax.fori_loop(
+        0, (n + chunk - 1) // chunk, step,
+        tuple(jnp.zeros((m[2],), dtype) for m in live)))
+    return tuple(next(hists) if len(m[0])
+                 else jnp.reshape(n, (1,)).astype(dtype) for m in metas)
 
 
 def sharded_marginals(domain: Domain, cliques: Sequence[Clique],
@@ -327,6 +344,121 @@ def sharded_marginals_program(domain: Domain, cliques: Sequence[Clique],
     out_specs = tuple(P() for _ in cliques)
     return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(in_spec,),
                                  out_specs=out_specs, check_vma=False))
+
+
+# Rows a store holds beyond its records when it is made: 1,024 releases of
+# 64 fresh rows each, so a serving window never fills it.
+HEADROOM_ROWS = 1 << 16
+
+
+def check_records(domain: Domain, rows) -> np.ndarray:
+    """``rows`` as an int32 ``(n, n_attrs)`` array, or ``ValueError`` unless
+    it is an integer array of that width whose every value lies in its
+    attribute's ``[0, size)``.  Messages name shapes and types, never data."""
+    arr = np.asarray(rows)
+    width = domain.n_attrs
+    if arr.ndim != 2 or arr.shape[1] != width:
+        raise ValueError(f"records must be an (n, {width}) array, got shape "
+                         f"{arr.shape}")
+    if arr.dtype.kind not in "iu":
+        raise ValueError(f"records must be integers, got {arr.dtype}")
+    if arr.size and ((arr.min(axis=0) < 0).any()
+                     or (arr.max(axis=0) >= np.asarray(domain.sizes)).any()):
+        raise ValueError("records hold a value outside its attribute's "
+                         "[0, size)")
+    return arr.astype(np.int32)
+
+
+# Each write replaces the store's buffer, so it may reuse the old one.
+@partial(jax.jit, donate_argnums=0)
+def _write_rows(buf, rows, at):
+    return jax.lax.dynamic_update_slice(buf, rows, (at, jnp.int32(0)))
+
+
+@lru_cache(maxsize=64)
+def _count_program(metas: tuple, dtype):
+    """Jitted ``(buffer, n) -> tables`` of a closure, shared by every store
+    of that closure and dtype."""
+    return jax.jit(lambda buf, n: _local_marginals(buf, metas, dtype, n))
+
+
+class RecordStore:
+    """One tenant's records, resident on the default device, and the count
+    of a plan closure's marginal tables from them.
+
+    The rows live in an int32 buffer of :attr:`capacity` rows, one
+    histogram chunk or whole chunks; the first :attr:`n` hold records and
+    the rest is headroom that :meth:`append` fills in order.  The count takes ``n``
+    as an argument, so a store that grows within its headroom compiles
+    nothing new.  A batch that does not fit at least doubles the capacity:
+    one copy into a new buffer, and one compile of the write and the count
+    for the new shape.
+    """
+
+    def __init__(self, domain: Domain, cliques: Sequence[Clique], records):
+        recs = check_records(domain, records)
+        self.domain = domain
+        self.cliques = tuple(cliques)
+        metas = []
+        for c in self.cliques:
+            strides, cells = _clique_strides(domain, c)
+            metas.append((tuple(c), tuple(int(x) for x in strides), cells))
+        self.metas = tuple(metas)
+        self.cells = sum(m[2] for m in self.metas)
+        live = [m for m in self.metas if m[0]]
+        self._chunk = _chunk_rows(live) if live else 1
+        self.n = recs.shape[0]
+        buf = np.zeros((self._rounded(self.n + HEADROOM_ROWS),
+                        domain.n_attrs), np.int32)
+        buf[:self.n] = recs
+        self._buf = jax.device_put(buf)
+
+    @property
+    def capacity(self) -> int:
+        return self._buf.shape[0]
+
+    def _rounded(self, rows: int) -> int:
+        """A capacity for ``rows``: itself within one histogram chunk, else
+        whole chunks (the count slices the buffer chunk by chunk)."""
+        rows = max(rows, 1)
+        if rows <= self._chunk:
+            return rows
+        return -(-rows // self._chunk) * self._chunk
+
+    def check_batch(self, rows, dtype) -> np.ndarray:
+        """A fresh batch as int32 rows (:func:`check_records`), or
+        ``ValueError`` where the store with it would hold more rows than
+        ``dtype`` counts exactly (2^24 at float32)."""
+        rows = check_records(self.domain, rows)
+        exact = 2 ** (jnp.finfo(dtype).nmant + 1)
+        if self.n + rows.shape[0] > exact:
+            raise ValueError(
+                f"{self.n + rows.shape[0]} records would pass {exact}, past "
+                f"which {jnp.dtype(dtype).name} counts are not exact")
+        return rows
+
+    def append(self, rows: np.ndarray) -> None:
+        """Write checked rows (:meth:`check_batch`) after the records."""
+        k = rows.shape[0]
+        if not k:
+            return
+        # Batches are written in power-of-two blocks, so varying batch sizes
+        # share a few programs; the block's pad rows lie past n.
+        block = 1 << (k - 1).bit_length()
+        if self.n + block > self.capacity:
+            grown = self._rounded(max(2 * self.capacity, self.n + block))
+            self._buf = jnp.pad(self._buf,
+                                ((0, grown - self.capacity), (0, 0)))
+        padded = np.zeros((block, rows.shape[1]), np.int32)
+        padded[:k] = rows
+        self._buf = _write_rows(self._buf, padded, np.int32(self.n))
+        self.n += k
+
+    def marginals(self, dtype) -> Tuple[jnp.ndarray, ...]:
+        """The tables of :attr:`cliques` over the first ``n`` rows, on the
+        device, in ``dtype``."""
+        return _count_program(self.metas, jnp.dtype(dtype))(
+            self._buf, np.int32(self.n))
 
 
 def sharded_measure(plan: BasePlan, records: jnp.ndarray,

@@ -4,13 +4,17 @@ The worker loop drains the request queue in small batches (up to
 ``max_batch`` requests, waiting at most ``max_wait_ms`` after the first to
 let a batch fill), then serves a batch in three phases:
 
-1. **validate + charge** — every request's marginals are validated against
-   the tenant's plan closure (keys + cell counts), and only then charged
-   against the durable ledger *before anything is measured*
-   (charge-before-measure, :mod:`repro.serve.ledger`) — a malformed request
-   never burns budget.  Over-budget requests fail immediately with the exact
-   remaining ρ; their future carries the
-   :class:`~repro.core.accountant.BudgetExhausted`.
+1. **validate + charge + count** — every request's marginals are validated
+   against the tenant's plan closure (keys + cell counts), or, for a tenant
+   that registered its records, its fresh rows against the domain (shape,
+   each value in range), and only then charged against the durable ledger
+   *before anything is measured* (charge-before-measure,
+   :mod:`repro.serve.ledger`) — a malformed request never burns budget.
+   Over-budget requests fail immediately with the exact remaining ρ; their
+   future carries the :class:`~repro.core.accountant.BudgetExhausted`.  A
+   records tenant's charged request then appends its rows to the records
+   the tenant holds on the device and counts its closure's tables from
+   them (:class:`~repro.engine.sharded.RecordStore`), in submission order.
 2. **fuse** — charged release requests whose plans are cross-request fusable
    (plain marginal plans, :func:`repro.engine.multi.can_fuse`) ride ONE
    fused chain launch per distinct per-axis signature across the whole batch
@@ -46,6 +50,7 @@ from repro.core.accountant import BudgetExhausted
 from repro.core.domain import Clique
 from repro.core.mechanism import noise_dtype, pcost_of_plan
 from repro.engine.multi import can_fuse, measure_multi
+from repro.engine.sharded import RecordStore
 from repro.kernels.kron_matvec._layout import resolve_use_kernel
 from repro.obs import REGISTRY, TRACER, exposition
 from repro.serve.ledger import BudgetLedger, UnknownTenant
@@ -63,18 +68,24 @@ class ReleaseRequest:
 
     ``kind="marginal"`` / ``"range"`` release the tenant's registered
     workload from the supplied exact marginal tables (``"range"`` merely
-    asserts the tenant holds an RP+ plan); ``kind="synthesis"`` samples
+    asserts the tenant holds an RP+ plan) or, for a tenant registered with
+    ``records=``, from the records it holds: ``records`` is then the fresh
+    ``(n, n_attrs)`` rows this release adds (``None``: none), and
+    ``marginals`` must be left out.  ``kind="synthesis"`` samples
     ``n_records`` rows from the tenant's last ``postprocess="nonneg"``
-    release (no new measurement → no budget charge).
+    release (no new measurement → no budget charge).  ``request_id`` names
+    the request's charge in the ledger's journal (default ``req-<n>``).
     """
 
     tenant: str
     kind: str = "marginal"
     marginals: Optional[Mapping[Clique, np.ndarray]] = None
+    records: Optional[np.ndarray] = None
     postprocess: Optional[str] = None
     n_records: int = 0
     seed: Optional[int] = None
     cliques: Optional[Sequence[Clique]] = None    # reconstruct subset
+    request_id: Optional[str] = None
 
 
 @dataclass
@@ -99,6 +110,7 @@ class _TenantSession:
     digits: int = 4
     synth_tables: Optional[dict] = None
     pcost_per_release: float = 0.0
+    records: Optional[RecordStore] = None     # touched by the worker only
 
 
 @dataclass
@@ -108,6 +120,8 @@ class _Pending:
     t_submit: float
     index: int                       # global request counter (noise fold)
     session: Optional[_TenantSession] = None
+    rows: Optional[np.ndarray] = None         # checked fresh records
+    marginals: Optional[Mapping[Clique, np.ndarray]] = None   # to measure
     measurements: Optional[dict] = None
     batched: bool = False
     charged: float = 0.0
@@ -198,19 +212,28 @@ class ReleaseServer:
     # ------------------------------------------------------------- tenants
     def register_tenant(self, tenant: str, plan, rho: Optional[float] = None,
                         pcost: Optional[float] = None, secure: bool = False,
-                        digits: int = 4, warm: bool = True) -> None:
+                        digits: int = 4, warm: bool = True,
+                        records=None) -> None:
         """Register a tenant: durable budget + serving plan (+ warm engine).
 
         ``rho``/``pcost`` set the tenant's total budget exactly as
         :meth:`BudgetLedger.register`.  ``secure=True`` serves this tenant
         through the discrete-Gaussian engine (charged the exact discrete
         pcost, always ≤ continuous).  ``warm=True`` compiles the engine into
-        the pool now so the first request is a cache hit.
+        the pool now so the first request is a cache hit.  ``records``, an
+        integer ``(n, n_attrs)`` array, makes the service hold the tenant's
+        records on the device: each release then counts its tables from
+        them after appending the request's fresh ``records``.
 
         Thread-safe against a running worker: the session map and the engine
         pool are lock-guarded, so tenants may be registered mid-traffic.
         """
+        store = None
+        if records is not None:
+            store = RecordStore(plan.domain, plan.cliques, records)
         self.ledger.register(tenant, rho=rho, pcost=pcost)
+        if store is not None:
+            self.stats.record_resident(tenant, store.n)
         if secure:
             from repro.core.discrete import discrete_pcost_of_plan
             per_release = discrete_pcost_of_plan(plan)
@@ -219,7 +242,7 @@ class ReleaseServer:
         with self._sessions_lock:
             self._sessions[tenant] = _TenantSession(
                 plan=plan, secure=secure, digits=digits,
-                pcost_per_release=per_release)
+                pcost_per_release=per_release, records=store)
         if warm:
             self.pool.engine_for(tenant, plan, self.use_kernel, self.dtype,
                                  secure, digits)
@@ -368,6 +391,45 @@ class ReleaseServer:
                     f"marginal for {c!r} has {got} cells, want {want} "
                     f"(nothing charged)")
 
+    @staticmethod
+    def _check_payload_kind(sess: _TenantSession,
+                            req: ReleaseRequest) -> None:
+        """A records tenant sends fresh rows, a tables tenant its tables;
+        the other payload fails the request before it is charged."""
+        if sess.records is not None:
+            if req.marginals is not None:
+                raise ValueError(
+                    f"tenant {req.tenant!r} registered its records: send "
+                    f"the fresh rows as records=, not marginals= (nothing "
+                    f"charged)")
+        elif req.records is not None:
+            raise ValueError(
+                f"tenant {req.tenant!r} registered no records: send "
+                f"marginals=, not records= (nothing charged)")
+        elif req.marginals is None:
+            raise ValueError(f"{req.kind!r} request needs marginals=")
+
+    def _count(self, p: _Pending,
+               store: RecordStore) -> Dict[Clique, np.ndarray]:
+        """Append the request's checked rows to the tenant's records and
+        count the plan closure's tables from them on the device; the tables
+        come back to the host once, for lane assembly."""
+        tenant = p.request.tenant
+        if p.rows is not None and len(p.rows):
+            with TRACER.span("records.ingest", parent=p.trace).set(
+                    rows=len(p.rows), bytes=p.rows.nbytes):
+                store.append(p.rows)
+            self.stats.record_resident(tenant, store.n)
+        with TRACER.span("records.scan", parent=p.trace).set(
+                rows=store.n, cliques=len(store.cliques), cells=store.cells):
+            tables = jax.block_until_ready(store.marginals(self.dtype))
+        self.stats.record_scan(tenant, store.n)
+        with TRACER.span("records.fetch", parent=p.trace) as sp:
+            tables = jax.device_get(tables)
+            if sp:
+                sp.set(bytes=sum(t.nbytes for t in tables))
+        return dict(zip(store.cliques, tables))
+
     def _serve_batch(self, batch) -> None:
         # Queue wait: an interval that started on the submitting thread —
         # recorded with an explicit t0 against each request's own trace.
@@ -390,20 +452,26 @@ class ReleaseServer:
                     raise UnknownTenant(req.tenant)
                 p.session = sess
                 if req.kind in RELEASE_KINDS:
-                    if req.marginals is None:
-                        raise ValueError(
-                            f"{req.kind!r} request needs marginals=")
+                    self._check_payload_kind(sess, req)
                     if req.kind == "range" and can_fuse(sess.plan):
                         raise ValueError(
                             "kind='range' needs an RP+ plan; this tenant "
                             "registered a plain marginal plan")
                     with TRACER.span("serve.validate", parent=p.trace):
-                        self._validate_marginals(sess, req)
+                        if sess.records is None:
+                            self._validate_marginals(sess, req)
+                            p.marginals = req.marginals
+                        elif req.records is not None:
+                            p.rows = sess.records.check_batch(req.records,
+                                                              self.dtype)
                     p.charged = sess.pcost_per_release
                     with TRACER.span("serve.charge", parent=p.trace).set(
                             tenant=req.tenant, pcost=p.charged):
-                        self.ledger.charge(req.tenant, p.charged,
-                                           request_id=f"req-{p.index}")
+                        self.ledger.charge(
+                            req.tenant, p.charged,
+                            request_id=req.request_id or f"req-{p.index}")
+                    if sess.records is not None:
+                        p.marginals = self._count(p, sess.records)
                 elif req.kind == "synthesis":
                     if sess.synth_tables is None:
                         raise ValueError(
@@ -422,7 +490,7 @@ class ReleaseServer:
                    and can_fuse(p.session.plan) and not p.session.secure]
         fused_groups = 0
         if len(fusable) >= 2:
-            items = [(p.session.plan, p.request.marginals, self._key_for(p))
+            items = [(p.session.plan, p.marginals, self._key_for(p))
                      for p in fusable]
             # The fused launch serves every fusable request at once, but a
             # span tree needs ONE parent: the batch leader's trace hosts the
@@ -505,7 +573,7 @@ class ReleaseServer:
                                       self.dtype, sess.secure, sess.digits)
         meas = p.measurements
         if meas is None:                      # solo path (RP+/secure/batch=1)
-            meas = engine.measure(req.marginals, self._key_for(p))
+            meas = engine.measure(p.marginals, self._key_for(p))
         tables = engine.reconstruct(meas, req.cliques) if req.cliques \
             else engine.reconstruct(meas)
         if req.postprocess is not None:

@@ -21,6 +21,10 @@ Metric names:
   ``repro_serve_queue_depth`` (gauge), ``repro_serve_queue_depth_max``.
 * ``repro_serve_fused_fallbacks_total`` — fused multi-request launches that
   raised and were served request by request instead.
+* ``repro_records_resident_rows{tenant}`` (gauge) — records a tenant
+  registered with the service holds on the device.
+* ``repro_records_scanned_rows_total{tenant}`` — records counted into
+  marginal tables, summed over the tenant's releases.
 
 Mutation comes from the worker thread plus the submit path while the /stats
 and /metrics HTTP threads read; every cell is an atomic counter, and queue
@@ -165,6 +169,12 @@ class ServerStats:
             "repro_serve_fused_fallbacks_total",
             "Fused multi-request launches that failed and were served on "
             "the solo path")
+        self._resident = self.registry.gauge(
+            "repro_records_resident_rows",
+            "Records a tenant holds on the device", labels=("tenant",))
+        self._scanned = self.registry.counter(
+            "repro_records_scanned_rows_total",
+            "Records counted into marginal tables", labels=("tenant",))
 
     # -- legacy field views ---------------------------------------------
     @property
@@ -181,6 +191,12 @@ class ServerStats:
 
     def record_fused_fallback(self) -> None:
         self._fused_fallbacks.inc()
+
+    def record_resident(self, tenant: str, rows: int) -> None:
+        self._resident.labels(tenant=tenant).set(rows)
+
+    def record_scan(self, tenant: str, rows: int) -> None:
+        self._scanned.labels(tenant=tenant).inc(rows)
 
     @property
     def queue_depth(self) -> int:

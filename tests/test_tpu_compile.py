@@ -115,3 +115,35 @@ def test_per_axis_chain_compiles_for_adult_100_cube(one_chip):
     # operand would be a regression
     assert ma.temp_size_in_bytes < 64 * 2 ** 20
     assert ma.argument_size_in_bytes < 2 * 4 * math.prod(dims) * 2
+
+
+def test_records_count_compiles_for_adult2(one_chip):
+    """``adult2``'s records count: every <=2-way Adult table over a store of
+    1,664,500 rows plus headroom, as ``RecordStore`` builds it.  The one-hot
+    blocks fuse into their reductions (no temporary the size of a block), and
+    the append writes the store in place."""
+    import itertools
+
+    from repro.core import Domain
+    from repro.engine import sharded
+
+    dom = Domain.create([100, 100, 100, 99, 85, 42, 16, 15, 9, 7, 6, 5, 2, 2])
+    closure = [()] + list(itertools.chain.from_iterable(
+        itertools.combinations(range(dom.n_attrs), k) for k in (1, 2)))
+    metas = tuple((c, tuple(int(x) for x in s), n) for c in closure
+                  for s, n in [sharded._clique_strides(dom, c)])
+    rows = sharded._chunk_rows(metas[1:])
+    capacity = -(-(1_664_500 + sharded.HEADROOM_ROWS) // rows) * rows
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    count = sharded._count_program(metas, jnp.dtype(jnp.float32)).lower(
+        spec((capacity, dom.n_attrs)), spec(())).compile()
+    ma = count.memory_analysis()
+    assert ma.temp_size_in_bytes < 64 * 2 ** 20
+    assert ma.output_size_in_bytes < 2 ** 20           # 148,726 float32 cells
+    write = sharded._write_rows.lower(spec((capacity, dom.n_attrs)),
+                                      spec((64, dom.n_attrs)),
+                                      spec(())).compile()
+    assert write.memory_analysis().alias_size_in_bytes > 0
