@@ -1,9 +1,10 @@
 """Mesh-sharded measurement engine (beyond-paper scale-out of Algorithm 1).
 
 Records are sharded over the ('pod','data') axes; every device builds partial
-marginal tables for the plan's closure as one-hot histograms (a one-hot of
-each record's cell code, summed over the records: a reduction, no scatter),
-partial tables are psum'd, and the residual transform + noise run replicated
+marginal tables for the plan's closure from one-hot blocks of its records
+(the tables of one- and two-attribute cliques as one Gram product of the
+attributes' one-hots, wider cliques as a sum of one-hots of their cell
+codes: a matmul and a reduction, no scatter), partial tables are psum'd, and the residual transform + noise run replicated
 (noise keys are identical across devices, so each device holds the same
 noisy answers — measurement is read-only on the records).
 
@@ -236,7 +237,7 @@ def _clique_strides(domain: Domain, clique: Clique) -> Tuple[np.ndarray, int]:
     return strides, int(np.prod(sizes)) if clique else 1
 
 
-# Largest one-hot block (records × cells) one histogram step builds: 2^24
+# Largest one-hot block (records × columns) one histogram step builds: 2^24
 # elements, 64 MiB at float32, whatever the record count.
 _ONE_HOT_ELEMS = 1 << 24
 
@@ -252,22 +253,49 @@ def _local_marginal(records, cols, strides, n_cells, dtype=None):
     return _local_marginals(records, [(cols, strides, n_cells)], dtype)[0]
 
 
+def _gram_columns(metas) -> Tuple[Dict[int, Tuple[int, int]], int]:
+    """``({attr: (offset, size)}, width)``: the one-hot columns of the Gram
+    product, one block per attribute of a one- or two-attribute clique in
+    ``metas``, in attribute order, and their count.  Sizes come from the
+    cliques' row-major strides."""
+    sizes = {}
+    for cols, strides, n_cells in metas:
+        if len(cols) == 1:
+            sizes[cols[0]] = n_cells
+        elif len(cols) == 2:
+            sizes[cols[0]] = n_cells // strides[0]
+            sizes[cols[1]] = strides[0]
+    cols, width = {}, 0
+    for a in sorted(sizes):
+        cols[a] = (width, sizes[a])
+        width += sizes[a]
+    return cols, width
+
+
 def _chunk_rows(metas) -> int:
-    """Rows one histogram step takes: the widest clique's one-hot block
-    stays within ``_ONE_HOT_ELEMS`` elements."""
-    return max(1, _ONE_HOT_ELEMS // max(m[2] for m in metas))
+    """Rows one histogram step takes: the widest one-hot block a step
+    builds (the Gram product's columns, or a wider clique's cells) stays
+    within ``_ONE_HOT_ELEMS`` elements."""
+    widest = max([_gram_columns(metas)[1]]
+                 + [m[2] for m in metas if len(m[0]) > 2])
+    return max(1, _ONE_HOT_ELEMS // widest)
 
 
 def _local_marginals(records, metas, dtype=None, n_rows=None):
     """One-hot histograms of several cliques, ``metas[i] = (cols, strides,
     n_cells)``, over the same records.
 
-    One loop consumes the records in chunks of ``_ONE_HOT_ELEMS //
-    max(n_cells)`` rows and builds every clique's table in its body, so no
-    ``(rows, n_cells)`` one-hot grows with N and the program holds one loop
-    whatever the clique count (a loop per clique, each with its own chunk
-    shape, made the TPU compile grow with N).  Rows past N in the last chunk
-    map to index -1, whose one-hot row is all zeros.
+    One loop consumes the records in chunks of :func:`_chunk_rows` rows, so
+    no one-hot block grows with N and the program holds one loop whatever
+    the clique count (a loop per clique, each with its own chunk shape,
+    made the TPU compile grow with N).  Each step builds a chunk's one-hot
+    block X over every attribute of a one- or two-attribute clique (0/1 in
+    bfloat16, exact) and adds XᵀX, a matmul on the MXU accumulated in float32
+    (float64 for float64 tables): block (a, b) of XᵀX is the a×b table and
+    the diagonal of block (a, a) is a's table, sliced out after the loop.
+    A clique of three or more attributes is summed as a one-hot of its cell
+    codes in the same step.  Rows past N map to index -1, whose one-hot row
+    is all zeros.
 
     ``n_rows`` (a traced scalar) counts only the first ``n_rows`` rows of a
     buffer that is one chunk or a whole number of them (:class:`RecordStore`):
@@ -283,25 +311,49 @@ def _local_marginals(records, metas, dtype=None, n_rows=None):
     chunk = max(1, min(records.shape[0], _chunk_rows(live)))
     if records.shape[0] % chunk:
         records = jnp.pad(records, ((0, -records.shape[0] % chunk), (0, 0)))
+    gram_cols, width = _gram_columns(live)
+    wide = [m for m in live if len(m[0]) > 2]
+    acc_dtype = jnp.promote_types(dtype, jnp.float32)
 
     def step(i, acc):
+        gram, hists = acc
         rec = jax.lax.dynamic_slice_in_dim(records, i * chunk, chunk)
         valid = i * chunk + jnp.arange(chunk) < n
+        if gram_cols:
+            x = jnp.concatenate(
+                [jax.nn.one_hot(jnp.where(valid, rec[:, a], -1), size,
+                                dtype=jnp.bfloat16)
+                 for a, (_o, size) in gram_cols.items()], axis=1)
+            gram = gram + jax.lax.dot_general(
+                x, x, (((0,), (0,)), ((), ())),
+                preferred_element_type=acc_dtype)
         out = []
-        for h, (cols, strides, n_cells) in zip(acc, live):
+        for h, (cols, strides, n_cells) in zip(hists, wide):
             flat = jnp.zeros((chunk,), jnp.int32)
             for c, s in zip(cols, strides):
                 flat = flat + rec[:, c] * int(s)
             flat = jnp.where(valid, flat, -1)
             out.append(h + jnp.sum(jax.nn.one_hot(flat, n_cells, dtype=dtype),
                                    axis=0))
-        return tuple(out)
+        return gram, tuple(out)
 
-    hists = iter(jax.lax.fori_loop(
+    gram, hists = jax.lax.fori_loop(
         0, (n + chunk - 1) // chunk, step,
-        tuple(jnp.zeros((m[2],), dtype) for m in live)))
-    return tuple(next(hists) if len(m[0])
-                 else jnp.reshape(n, (1,)).astype(dtype) for m in metas)
+        (jnp.zeros((width, width), acc_dtype),
+         tuple(jnp.zeros((m[2],), dtype) for m in wide)))
+    hists = iter(hists)
+
+    def table(cols):
+        if not cols:
+            return jnp.reshape(n, (1,)).astype(dtype)
+        if len(cols) > 2:
+            return next(hists)
+        (oa, sa), (ob, sb) = gram_cols[cols[0]], gram_cols[cols[-1]]
+        block = gram[oa:oa + sa, ob:ob + sb]
+        flat = jnp.diagonal(block) if len(cols) == 1 else block.reshape(-1)
+        return flat.astype(dtype)
+
+    return tuple(table(m[0]) for m in metas)
 
 
 def sharded_marginals(domain: Domain, cliques: Sequence[Clique],
@@ -405,6 +457,8 @@ class RecordStore:
             metas.append((tuple(c), tuple(int(x) for x in strides), cells))
         self.metas = tuple(metas)
         self.cells = sum(m[2] for m in self.metas)
+        # Cliques the Gram product counts (:func:`_local_marginals`).
+        self.product_cliques = sum(1 <= len(m[0]) <= 2 for m in self.metas)
         live = [m for m in self.metas if m[0]]
         self._chunk = _chunk_rows(live) if live else 1
         self.n = recs.shape[0]

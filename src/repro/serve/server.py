@@ -421,7 +421,8 @@ class ReleaseServer:
                 store.append(p.rows)
             self.stats.record_resident(tenant, store.n)
         with TRACER.span("records.scan", parent=p.trace).set(
-                rows=store.n, cliques=len(store.cliques), cells=store.cells):
+                rows=store.n, cliques=len(store.cliques),
+                product_cliques=store.product_cliques, cells=store.cells):
             tables = jax.block_until_ready(store.marginals(self.dtype))
         self.stats.record_scan(tenant, store.n)
         with TRACER.span("records.fetch", parent=p.trace) as sp:

@@ -109,9 +109,10 @@ def test_records_tenant_releases_bit_equal_tables_tenant(plan, tmp_path,
 
 def test_store_tables_equal_bincount(plan, monkeypatch):
     """Appends of several sizes (empty, one row, a partial block, a whole
-    one) into a store whose buffer is whole chunks of 32 rows, with rows of
-    headroom past the records, until the buffer fills and grows."""
-    monkeypatch.setattr(sharded, "_ONE_HOT_ELEMS", 32 * 15)
+    one) into a store whose buffer is whole chunks of 32 rows (a one-hot
+    block of the 17 attribute values), with rows of headroom past the
+    records, until the buffer fills and grows."""
+    monkeypatch.setattr(sharded, "_ONE_HOT_ELEMS", 32 * 17)
     monkeypatch.setattr(sharded, "HEADROOM_ROWS", 20)
     store = RecordStore(DOM, plan.cliques, _records(0, 70))
     assert store.capacity == 96                  # 90 rows, in chunks of 32
@@ -224,8 +225,11 @@ def test_records_spans(plan, tmp_path):
     cells = sum(DOM.n_cells(c) for c in plan.cliques)
     assert named["records.ingest"].attrs == {"rows": BATCH,
                                              "bytes": BATCH * 5 * 4}
+    # Every clique but the empty one is counted by the Gram product.
+    assert () in plan.cliques
     assert named["records.scan"].attrs == {
-        "rows": 400 + BATCH, "cliques": len(plan.cliques), "cells": cells}
+        "rows": 400 + BATCH, "cliques": len(plan.cliques),
+        "product_cliques": len(plan.cliques) - 1, "cells": cells}
     assert named["records.fetch"].attrs["bytes"] == 4 * cells
     with open(os.path.join(str(tmp_path), "ledger.jsonl")) as fh:
         charges = [json.loads(line) for line in fh if '"charge"' in line]

@@ -119,9 +119,11 @@ def test_per_axis_chain_compiles_for_adult_100_cube(one_chip):
 
 def test_records_count_compiles_for_adult2(one_chip):
     """``adult2``'s records count: every <=2-way Adult table over a store of
-    1,664,500 rows plus headroom, as ``RecordStore`` builds it.  The one-hot
-    blocks fuse into their reductions (no temporary the size of a block), and
-    the append writes the store in place."""
+    1,664,500 rows plus headroom, as ``RecordStore`` builds it.  The tables
+    come from one Gram product of the records' one-hot block a chunk, a
+    matmul on the MXU (a ``dot`` or ``convolution`` in the compiled HLO), with
+    no temporary the size of a block, and the append writes the store in
+    place."""
     import itertools
 
     from repro.core import Domain
@@ -143,6 +145,8 @@ def test_records_count_compiles_for_adult2(one_chip):
     ma = count.memory_analysis()
     assert ma.temp_size_in_bytes < 64 * 2 ** 20
     assert ma.output_size_in_bytes < 2 ** 20           # 148,726 float32 cells
+    hlo = count.as_text()
+    assert " convolution(" in hlo or " dot(" in hlo
     write = sharded._write_rows.lower(spec((capacity, dom.n_attrs)),
                                       spec((64, dom.n_attrs)),
                                       spec(())).compile()
